@@ -21,7 +21,7 @@ the update a single ``einsum``-shaped reduction (DP-shardable over batch).
 
 This module is the *functional reference*; :mod:`repro.engine.train`
 provides interchangeable ``TrainEngine`` backends (bit-packed SWAR clause
-eval, a fused Pallas delta kernel) that are delta-exact with it for the
+eval, a fused delta body) that are delta-exact with it for the
 same PRNG key.  The PRNG contract that makes them exchangeable lives in
 :func:`feedback_masks` / :func:`feedback_update`: every backend splits the
 step key identically, derives the same per-row threefry keys, and draws

@@ -1,16 +1,13 @@
-"""Autotuner for tile-parameterized VoteEngine and TrainEngine backends.
+"""Autotuner for tile-parameterized VoteEngine backends.
 
 ``mxu_fused`` and ``swar_fused`` take ``block_b``/``block_cm`` tile sizes
-that used to be hardcoded guesses; the ``fused`` training backend
-likewise takes ``block_b``/``block_m`` (swept under the key
-``train:fused``).  This module sweeps each backend's candidate grid per
-TM shape, times the jitted ``infer`` (or ``step``) end to end, and
+that used to be hardcoded guesses.  This module sweeps each backend's
+candidate grid per TM shape, times the jitted ``infer`` end to end, and
 persists the winners to a JSON cache (``benchmarks/autotune_cache.json``
 by default, overridable via ``REPRO_AUTOTUNE_CACHE``).  ``get_engine``
-and ``get_train_engine`` consult :func:`lookup` on every build, so once a
-shape has been tuned on a device kind, every engine constructed for it
-uses the measured-best tiles instead of the defaults — explicitly passed
-opts always win.
+consults :func:`lookup` on every build, so once a shape has been tuned on
+a device kind, every engine constructed for it uses the measured-best
+tiles instead of the defaults — explicitly passed opts always win.
 
 Cache entries are keyed by ``backend|C|M|L|device_kind``: tile choice
 depends on the clause geometry and the compiler target, not on the exact
@@ -41,19 +38,11 @@ __all__ = ["SEARCH_SPACE", "cache_path", "device_kind", "shape_key",
            "autotune_backend", "run_sweep"]
 
 # candidate tiles per tunable backend; every combination is measured.
-# "train:<name>" keys tune TrainEngine backends (repro.engine.train) —
-# their tiles shape the Pallas kernel path, so on a CPU (interpret) sweep
-# the candidates tie and the entry is a no-op placeholder until a TPU
-# sweep refreshes it.
 SEARCH_SPACE: dict[str, dict[str, tuple[int, ...]]] = {
     "mxu_fused": {"block_b": (32, 64, 128, 256),
                   "block_cm": (64, 128, 256)},
     "swar_fused": {"block_b": (8, 16, 32, 64),
                    "block_cm": (64, 128, 256)},
-    "train:fused": {"block_b": (32, 64, 128),
-                    "block_m": (32, 64, 128)},
-    "train:sparse": {"block_b": (32, 64, 128),
-                     "block_m": (32, 64, 128)},
     # early-exit cascade: exits need a stage-1 margin ≥ the remainder
     # size, so fractions below ~0.5 can never pay off — the grid starts
     # there.  The winner depends on the state's margin distribution, so
@@ -150,37 +139,19 @@ def autotune_backend(backend: str, cfg, state, batches, *,
     """Sweep ``SEARCH_SPACE[backend]`` for one (cfg, state).
 
     ``batches``: iterable of (B, L) literal arrays to measure over.
-    → (best param dict, all measurement rows).  ``train:<name>`` backends
-    time ``engine.step`` (with fixed labels/key per batch) instead of
-    ``infer``.
+    → (best param dict, all measurement rows).
     """
     from .base import _REGISTRY
     from . import backends  # noqa: F401  (registration side effect)
     space = SEARCH_SPACE[backend]
     names, grids = zip(*space.items())
-    is_train = backend.startswith("train:")
-    if is_train:
-        import jax
-        from .train import get_train_engine
-        key = jax.random.key(0)
-        rng = np.random.default_rng(1)
-        labels = [jnp.asarray(rng.integers(0, cfg.n_classes,
-                                           lits.shape[0]), jnp.int32)
-                  for lits in batches]
     rows, best, best_us = [], {}, float("inf")
     for combo in itertools.product(*grids):
         params = dict(zip(names, combo))
         try:
-            if is_train:
-                engine = get_train_engine(backend.removeprefix("train:"),
-                                          cfg, cache=False, **params)
-                total = sum(_time_us(engine.step, state, key, lits, y,
-                                     repeat=repeat)
-                            for lits, y in zip(batches, labels))
-            else:
-                engine = _REGISTRY[backend](cfg, state, **params)
-                total = sum(_time_us(engine.infer, lits, repeat=repeat)
-                            for lits in batches)
+            engine = _REGISTRY[backend](cfg, state, **params)
+            total = sum(_time_us(engine.infer, lits, repeat=repeat)
+                        for lits in batches)
         except Exception as exc:      # invalid tile for this shape/target
             rows.append({"backend": backend, **params, "error": str(exc)})
             continue

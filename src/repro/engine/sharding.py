@@ -1,10 +1,11 @@
 """Batch-axis sharding for any VoteEngine: multi-device serving.
 
-``ShardedEngine`` wraps an engine's ``infer`` in a ``shard_map`` over a
-1-D ``("batch",)`` mesh of all local devices: each device runs the inner
-backend on its batch shard, and results concatenate back on the batch
-axis.  Works for every backend because ``EngineResult`` leaves (prediction,
-class_sums, aux arrays) are all batch-leading by contract.
+``ShardedEngine`` wraps an engine's ``infer`` in a jitted ``shard_map``
+over a 1-D ``("batch",)`` mesh of all local devices: each device runs the
+inner backend on its batch shard, and results concatenate back on the
+batch axis; each batch shape compiles once.  Works for every backend
+because ``EngineResult`` leaves (prediction, class_sums, aux arrays) are
+all batch-leading by contract.
 
 Ragged batches pad to a device multiple with all-zero literal rows (a
 valid input — clauses evaluate normally) and slice back after the map,
@@ -16,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .base import EngineResult, VoteEngine
@@ -55,9 +55,9 @@ class ShardedEngine:
             self.mesh = Mesh(np.array(devs), ("batch",))
         axis = self.mesh.axis_names[0]
         self.n_devices = self.mesh.shape[axis]
-        self._sharded = shard_map(
+        self._sharded = jax.jit(jax.shard_map(
             inner.infer, mesh=self.mesh,
-            in_specs=P(axis), out_specs=P(axis), check_rep=False)
+            in_specs=P(axis), out_specs=P(axis), check_vma=False))
 
     def infer(self, literals: jax.Array) -> EngineResult:
         """(B, 2F) literals → the inner engine's result, batch-sharded

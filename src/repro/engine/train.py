@@ -33,23 +33,19 @@ is purely a performance decision, exactly like inference.
 ``packed``              bit-packed literals + SWAR clause evaluation (the
                         ``swar_packed`` inference layout) feeding the
                         shared feedback math — clause eval as word-ANDs.
-``fused``               SWAR-fused class sums plus a Pallas kernel
-                        (``train_deltas_pallas``) fusing addressed-class
-                        clause eval + Type I/II delta generation + the
-                        per-class scatter, so no per-sample delta tensor
-                        ever materializes in HBM.
+``fused``               SWAR-fused class sums (the ``swar_fused`` Pallas
+                        kernel on TPU) plus one XLA body fusing
+                        addressed-class clause eval + Type I/II delta
+                        generation + the class-free per-class scatter
+                        (:func:`repro.kernels.train_fused.train_deltas`).
 ``sparse``              clause-indexed: class sums come from the ELL
                         gather path (:mod:`repro.kernels.ell_gather`) on
                         an incrementally-refreshed layout
                         (:class:`repro.engine.sparse.IncrementalEll`),
-                        then the fused delta kernel applies feedback —
+                        then the fused delta body applies feedback —
                         O(R·K) clause eval instead of O(R·L) at trained
                         include densities.
 ======================  ====================================================
-
-``fused`` and ``sparse`` take ``block_b``/``block_m`` tile opts; when not
-given explicitly, :func:`get_train_engine` consults the autotune cache
-(key ``train:<name>|C|M|L|device``) before falling back to the defaults.
 
 The one exception to "no state-derived layout" above is ``sparse``: its
 ELL index matrix *is* state-derived, so the engine carries an
@@ -70,7 +66,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.popcount import pack_bits
@@ -83,8 +78,7 @@ from repro.kernels.clause_eval import make_vote_matrix
 from repro.kernels.ell_gather import ell_clause_votes
 from repro.kernels.ops import on_tpu
 from repro.kernels.swar_fused import swar_fused_votes_pallas
-from repro.kernels.train_fused import (DEFAULT_BLOCK_B, DEFAULT_BLOCK_M,
-                                       feedback_polarity_masks, train_deltas)
+from repro.kernels.train_fused import feedback_polarity_masks, train_deltas
 
 from .backends import swar_clauses_votes
 from .base import KeyedEngineCache, Registry, _cache_key
@@ -145,20 +139,13 @@ def get_train_engine(name: str, cfg: TMConfig, *, cache: bool = True,
     """Build (or fetch from cache) the named training backend's engine.
 
     Extra ``opts`` are forwarded to the backend constructor (e.g.
-    ``boost_tpf=False``, or ``block_b``/``block_m`` tiles for ``fused``).
-    Tunable backends whose tile opts are not given explicitly get them
-    from the autotune cache (:mod:`repro.engine.autotune`, keyed
-    ``train:<name>``) when an entry for this shape exists.
+    ``boost_tpf=False``, or ``k_slack`` for ``sparse``).
 
     ``cache=True`` (default) memoizes built engines by (backend, cfg,
     options) in a small keyed LRU — no state in the key, because train
     engines derive nothing from the state at build time (the state is a
     per-step argument).
     """
-    from . import autotune
-    for opt, val in autotune.lookup(f"train:{name}", cfg).items():
-        opts.setdefault(opt, val)
-
     key = _cache_key(name, cfg, (), opts) if cache else None
     if key is not None:
         hit = _TRAIN_CACHE.get(key)
@@ -193,13 +180,18 @@ def import_key_cursor(data, impl: str) -> jax.Array:
 
 
 def train_engine_opts(engine: TrainEngine) -> dict:
-    """The constructor opts a built engine was resolved with — the
-    autotune picks a checkpoint must persist so a restore on a different
-    host rebuilds the *same* engine rather than re-consulting a possibly
-    different autotune cache.  Backends expose this via
-    ``lifecycle_opts``; engines without it snapshot nothing."""
+    """The constructor opts a built engine was resolved with — what a
+    checkpoint must persist so a restore on a different host rebuilds the
+    *same* engine.  Backends expose this via ``lifecycle_opts``; engines
+    without it snapshot nothing."""
     fn = getattr(engine, "lifecycle_opts", None)
     return dict(fn()) if fn is not None else {}
+
+
+# tile opts of the retired Pallas delta kernel: checkpoints written while
+# the fused/sparse/sharded trainers took them still persist them, and a
+# restore must drop them rather than pass them to today's constructors
+RETIRED_TRAIN_OPTS = ("block_b", "block_m")
 
 
 def _packed_clauses_votes(cfg, state, x, pos_mask, neg_mask):
@@ -224,10 +216,9 @@ def _packed_step(cfg, state, key, x, y, pos_mask, neg_mask, *, boost_tpf):
                            boost_tpf=boost_tpf)
 
 
-def _deltas_from_votes(cfg, state, key, x, y, votes, *, boost_tpf,
-                       block_b, block_m, interpret):
+def _deltas_from_votes(cfg, state, key, x, y, votes, *, boost_tpf):
     """Shared tail of the fused/sparse steps: feedback masks → raw
-    uniform words → fused delta kernel → clipped new state.
+    uniform words → fused delta body → clipped new state.
 
     Every input bit downstream of ``votes`` is backend-independent, so
     any two backends that produce bit-identical ``votes`` and share this
@@ -251,17 +242,15 @@ def _deltas_from_votes(cfg, state, key, x, y, votes, *, boost_tpf,
     p_inc = 1.0 if boost_tpf else (cfg.s - 1.0) / cfg.s
     upd = train_deltas(x, bits1, bits2, inc8[y], inc8[y_neg],
                        m1_t, m2_t, m1_n, m2_n, y, y_neg,
-                       n_classes=c, p_inc=p_inc, p_dec=1.0 / cfg.s,
-                       block_b=block_b, block_m=block_m,
-                       interpret=interpret)
+                       n_classes=c, p_inc=p_inc, p_dec=1.0 / cfg.s)
     ta = jnp.clip(state.ta + upd, 1, 2 * cfg.n_states)
     return TMState(ta=ta)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "boost_tpf", "block_b",
-                                             "block_m", "interpret"))
+@functools.partial(jax.jit, static_argnames=("cfg", "boost_tpf",
+                                             "interpret"))
 def _fused_step(cfg, state, key, x, y, vm, pos_mask, neg_mask, *, boost_tpf,
-                block_b, block_m, interpret):
+                interpret):
     c, m = cfg.n_classes, cfg.n_clauses
     if interpret:
         # CPU: SWAR word votes as straight-line XLA (the vote kernel's
@@ -274,29 +263,23 @@ def _fused_step(cfg, state, key, x, y, vm, pos_mask, neg_mask, *, boost_tpf,
         votes = swar_fused_votes_pallas(not_words, inc_words, vm,
                                         interpret=False)         # (B, C)
     return _deltas_from_votes(cfg, state, key, x, y, votes,
-                              boost_tpf=boost_tpf, block_b=block_b,
-                              block_m=block_m, interpret=interpret)
+                              boost_tpf=boost_tpf)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "boost_tpf", "block_b",
-                                             "block_m", "interpret"))
-def _sparse_step(cfg, state, key, x, y, indices, *, boost_tpf, block_b,
-                 block_m, interpret):
+@functools.partial(jax.jit, static_argnames=("cfg", "boost_tpf"))
+def _sparse_step(cfg, state, key, x, y, indices, *, boost_tpf):
     """Clause-indexed step: votes from the ELL gather over ``indices``
     (which the caller guarantees matches ``state``'s include mask), then
     the shared fused-delta tail."""
     c, m = cfg.n_classes, cfg.n_clauses
     _, votes = ell_clause_votes(indices, clause_polarity(m), x, c=c, m=m)
     return _deltas_from_votes(cfg, state, key, x, y, votes,
-                              boost_tpf=boost_tpf, block_b=block_b,
-                              block_m=block_m, interpret=interpret)
+                              boost_tpf=boost_tpf)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "mesh", "boost_tpf",
-                                             "block_b", "block_m",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh", "boost_tpf"))
 def _sharded_step(cfg, state, key, x, y, pos_mask, neg_mask, *, mesh,
-                  boost_tpf, block_b, block_m, interpret):
+                  boost_tpf):
     """Data-parallel train step over a 1-D mesh, bit-identical to
     ``_fused_step`` for any device count.
 
@@ -388,8 +371,7 @@ def _sharded_step(cfg, state, key, x, y, pos_mask, neg_mask, *, mesh,
         upd = train_deltas(x_s, gen(k1_s), gen(k2_s), inc[y_s], inc[y_neg],
                            m1_t, m2_t, m1_n, m2_n, y_s, y_neg,
                            n_classes=cp, p_inc=p_inc, p_dec=1.0 / cfg.s,
-                           block_b=block_b, block_m=block_m,
-                           interpret=interpret, widen=not narrow)
+                           widen=not narrow)
         # reduce-scatter the class-segmented partials so the O(C·M·L)
         # clip runs on each device's class slice, then reassemble
         upd_s = jax.lax.psum_scatter(upd, axis, scatter_dimension=0,
@@ -403,9 +385,9 @@ def _sharded_step(cfg, state, key, x, y, pos_mask, neg_mask, *, mesh,
     # JAX reassembles the replicated view lazily only when a consumer
     # (inference, checkpointing) actually reads it
     rep, sh = P(), P(axis)
-    ta = shard_map(body, mesh=mesh,
-                   in_specs=(sh, rep, rep, sh, sh, sh, sh, sh, sh),
-                   out_specs=sh, check_rep=False)(
+    ta = jax.shard_map(body, mesh=mesh,
+                       in_specs=(sh, rep, rep, sh, sh, sh, sh, sh, sh),
+                       out_specs=sh, check_vma=False)(
         ta, pos_mask, neg_mask, x, y, offs, u, k1s, k2s)
     return TMState(ta=ta[:c])
 
@@ -427,14 +409,10 @@ class ShardedTrainEngine:
     :func:`repro.distributed.sharding.data_mesh` over that many local
     devices (``None`` = all).  Fully traceable — no host callbacks — so
     the ``train_epoch`` ``lax.scan`` path shards each scanned step.
-    ``block_b``/``block_m`` tile the delta kernel per shard (autotune key
-    ``train:sharded``).
     """
 
     def __init__(self, cfg: TMConfig, *, boost_tpf: bool = True,
-                 n_devices: int | None = None, mesh=None,
-                 block_b: int = DEFAULT_BLOCK_B,
-                 block_m: int = DEFAULT_BLOCK_M):
+                 n_devices: int | None = None, mesh=None):
         self.cfg = cfg
         self.boost_tpf = boost_tpf
         if mesh is not None:
@@ -446,7 +424,6 @@ class ShardedTrainEngine:
         else:
             self.mesh = data_mesh(n_devices)
         self.n_devices = self.mesh.shape[self.mesh.axis_names[0]]
-        self._blocks = (block_b, block_m)
         pol = clause_polarity(cfg.n_clauses)
         self._pos_mask = pack_bits((pol > 0).astype(jnp.int8))   # (Wm,)
         self._neg_mask = pack_bits((pol < 0).astype(jnp.int8))
@@ -456,10 +433,7 @@ class ShardedTrainEngine:
         """One mesh-sharded update (see :class:`TrainEngine`)."""
         return _sharded_step(self.cfg, state, key, x_literals, y,
                              self._pos_mask, self._neg_mask,
-                             mesh=self.mesh, boost_tpf=self.boost_tpf,
-                             block_b=self._blocks[0],
-                             block_m=self._blocks[1],
-                             interpret=not on_tpu())
+                             mesh=self.mesh, boost_tpf=self.boost_tpf)
 
     def lifecycle_opts(self) -> dict:
         """Constructor opts to persist in a checkpoint (see
@@ -467,8 +441,7 @@ class ShardedTrainEngine:
         mesh: devices are host-local, and a restore host clamps or
         overrides the size (elastic restore) — safe because training is
         mesh-size invariant."""
-        return {"boost_tpf": self.boost_tpf, "n_devices": self.n_devices,
-                "block_b": self._blocks[0], "block_m": self._blocks[1]}
+        return {"boost_tpf": self.boost_tpf, "n_devices": self.n_devices}
 
 
 @register_train_backend("reference")
@@ -534,22 +507,17 @@ class FusedTrainEngine:
     shared PRNG contract; then the fused delta computation
     (``repro.kernels.train_fused.train_deltas``) does addressed-class
     clause eval + Type I/II delta generation + a class-free segment-sum
-    scatter in one pass, so the six per-sample ``(B, M, 2F)`` delta
-    tensors of the reference are never written out.  ``block_b`` /
-    ``block_m`` tile the Pallas kernel path and are autotunable
-    (autotune key ``train:fused``).
+    scatter in one jitted body instead of the reference's six per-sample
+    ``(B, M, 2F)`` delta tensors and one-hot einsums.
     """
 
-    def __init__(self, cfg: TMConfig, *, boost_tpf: bool = True,
-                 block_b: int = DEFAULT_BLOCK_B,
-                 block_m: int = DEFAULT_BLOCK_M):
+    def __init__(self, cfg: TMConfig, *, boost_tpf: bool = True):
         self.cfg = cfg
         self.boost_tpf = boost_tpf
         self._vm = make_vote_matrix(cfg.n_classes, cfg.n_clauses)
         pol = clause_polarity(cfg.n_clauses)
         self._pos_mask = pack_bits((pol > 0).astype(jnp.int8))   # (Wm,)
         self._neg_mask = pack_bits((pol < 0).astype(jnp.int8))
-        self._blocks = (block_b, block_m)
 
     def step(self, state: TMState, key: jax.Array, x_literals: jax.Array,
              y: jax.Array) -> TMState:
@@ -557,15 +525,12 @@ class FusedTrainEngine:
         return _fused_step(self.cfg, state, key, x_literals, y, self._vm,
                            self._pos_mask, self._neg_mask,
                            boost_tpf=self.boost_tpf,
-                           block_b=self._blocks[0],
-                           block_m=self._blocks[1],
                            interpret=not on_tpu())
 
     def lifecycle_opts(self) -> dict:
-        """Constructor opts to persist in a checkpoint — including the
-        resolved autotune tile picks (see :func:`train_engine_opts`)."""
-        return {"boost_tpf": self.boost_tpf,
-                "block_b": self._blocks[0], "block_m": self._blocks[1]}
+        """Constructor opts to persist in a checkpoint (see
+        :func:`train_engine_opts`)."""
+        return {"boost_tpf": self.boost_tpf}
 
 
 @register_train_backend("sparse")
@@ -584,28 +549,22 @@ class SparseTrainEngine:
     and negative classes get feedback), with a full vectorized rebuild
     only on K overflow or ``rebuild_threshold`` cumulative drift.
 
-    Wins over ``fused`` when include density is low enough that clause
-    eval dominates the step (small B, large L); loses when the fused
-    Pallas vote kernel is already memory-bound or the state is dense —
-    see docs/training.md for the measured crossover.  Under a trace
+    Meant to win over ``fused`` when include density is low enough that
+    clause eval dominates the step (small B, large L); the crossover has
+    not been measured on a chip.  Under a trace
     (``train_epoch``'s ``lax.scan``) the host-side refresh is impossible,
     so :meth:`step` falls back to the bit-identical packed step.
 
-    ``block_b``/``block_m`` tile the delta kernel (autotune key
-    ``train:sparse``); ``k_slack``/``rebuild_threshold`` tune the layout
-    refresh policy.
+    ``k_slack``/``rebuild_threshold`` tune the layout refresh policy.
     """
 
     def __init__(self, cfg: TMConfig, *, boost_tpf: bool = True,
                  k_slack: int = DEFAULT_K_SLACK,
-                 rebuild_threshold: float = DEFAULT_REBUILD_THRESHOLD,
-                 block_b: int = DEFAULT_BLOCK_B,
-                 block_m: int = DEFAULT_BLOCK_M):
+                 rebuild_threshold: float = DEFAULT_REBUILD_THRESHOLD):
         self.cfg = cfg
         self.boost_tpf = boost_tpf
         self.k_slack = int(k_slack)
         self.rebuild_threshold = float(rebuild_threshold)
-        self._blocks = (block_b, block_m)
         self._ell: IncrementalEll | None = None
         pol = clause_polarity(cfg.n_clauses)
         self._pos_mask = pack_bits((pol > 0).astype(jnp.int8))   # (Wm,)
@@ -635,10 +594,7 @@ class SparseTrainEngine:
                                 boost_tpf=self.boost_tpf)
         indices = self._refresh(state)
         return _sparse_step(self.cfg, state, key, x_literals, y, indices,
-                            boost_tpf=self.boost_tpf,
-                            block_b=self._blocks[0],
-                            block_m=self._blocks[1],
-                            interpret=not on_tpu())
+                            boost_tpf=self.boost_tpf)
 
     def layout_stats(self) -> dict | None:
         """Refresh counters of the engine's :class:`IncrementalEll`
@@ -646,8 +602,7 @@ class SparseTrainEngine:
         return None if self._ell is None else self._ell.stats()
 
     def lifecycle_opts(self) -> dict:
-        """Constructor opts to persist in a checkpoint — including the
-        resolved autotune tile picks (see :func:`train_engine_opts`)."""
+        """Constructor opts to persist in a checkpoint (see
+        :func:`train_engine_opts`)."""
         return {"boost_tpf": self.boost_tpf, "k_slack": self.k_slack,
-                "rebuild_threshold": self.rebuild_threshold,
-                "block_b": self._blocks[0], "block_m": self._blocks[1]}
+                "rebuild_threshold": self.rebuild_threshold}

@@ -7,9 +7,12 @@ to (winner, first-arrival latency, metastability flag) *inside the kernel*,
 so per-class delays never leave VMEM — mirroring the race fusing popcount
 with comparison.
 
-Tiling: grid ``(B/bb,)``; each step holds the full (C, M) delay tables in
-VMEM (TM scale: C ≤ 128 classes, M ≤ a few K clauses), computes the (bb, C)
-delay matrix and reduces it. Outputs are (bb, 1)-padded lanes.
+Tiling: grid ``(B/bb,)``; each step holds a ``(bb, C, M)`` select block
+and the full (C, M) delay tables in VMEM (TM scale: C ≤ 128 classes, M ≤ a
+few K clauses), computes the (bb, C) delay matrix and reduces it. The
+blocks span the whole (C, M) extent, so any C and M compile (no in-kernel
+reshape). The arbiter resolution is one scalar in SMEM. Outputs are
+(bb, 1)-padded lanes.
 """
 
 from __future__ import annotations
@@ -19,20 +22,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["pdl_race_pallas"]
 
 
 def _pdl_race_kernel(sel_ref, low_ref, high_ref, skew_ref, res_ref,
                      win_ref, lat_ref, meta_ref):
-    sel = sel_ref[...].astype(jnp.float32)                  # (bb, C*M) flat
-    bb = sel.shape[0]
-    c, m = low_ref.shape
-    sel = sel.reshape(bb, c, m)
+    sel = sel_ref[...].astype(jnp.float32)                  # (bb, C, M)
     low = low_ref[...][None]                                # (1, C, M)
     high = high_ref[...][None]
     per = sel * low + (1.0 - sel) * high
-    delays = per.sum(-1) + skew_ref[...].reshape(1, c)      # (bb, C)
+    delays = per.sum(-1) + skew_ref[...]                    # (bb, C)
 
     lat = jnp.min(delays, axis=-1, keepdims=True)           # (bb, 1)
     win = jnp.argmin(delays, axis=-1, keepdims=True).astype(jnp.int32)
@@ -59,7 +60,7 @@ def pdl_race_pallas(low_sel: jax.Array, elem_delays: jax.Array,
     """
     b, c, m = low_sel.shape
     bp = -(-b // block_b) * block_b
-    sel = jnp.pad(low_sel, ((0, bp - b), (0, 0), (0, 0))).reshape(bp, c * m)
+    sel = jnp.pad(low_sel, ((0, bp - b), (0, 0), (0, 0)))
     low = elem_delays[..., 0]
     high = elem_delays[..., 1]
     res = jnp.full((1, 1), t_res, jnp.float32)
@@ -68,11 +69,11 @@ def pdl_race_pallas(low_sel: jax.Array, elem_delays: jax.Array,
         _pdl_race_kernel,
         grid=(bp // block_b,),
         in_specs=[
-            pl.BlockSpec((block_b, c * m), lambda i: (i, 0)),
+            pl.BlockSpec((block_b, c, m), lambda i: (i, 0, 0)),
             pl.BlockSpec((c, m), lambda i: (0, 0)),
             pl.BlockSpec((c, m), lambda i: (0, 0)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pl.ANY),
+            pl.BlockSpec((1, c), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
@@ -85,5 +86,5 @@ def pdl_race_pallas(low_sel: jax.Array, elem_delays: jax.Array,
             jax.ShapeDtypeStruct((bp, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(sel, low, high, skew, res)
+    )(sel, low, high, skew.reshape(1, c), res)
     return win[:b, 0], lat[:b, 0], meta[:b, 0].astype(bool)

@@ -1,4 +1,4 @@
-"""Fused TM clause-eval + Type I/II feedback-delta update (Pallas + jnp).
+"""Fused TM clause-eval + Type I/II feedback-delta update (one XLA body).
 
 The reference training step (``repro.core.tm_train.feedback_update``)
 materializes *six* per-sample ``(B, M, 2F)`` int32 tensors in HBM — two
@@ -18,17 +18,10 @@ Type I/II roles swapped by the ``m*_n`` masks).  The per-class scatter is
 a *class-free* batch segment-sum — ``O(B·M·2F)`` adds instead of the
 reference's ``O(B·C·M·2F)`` one-hot matmuls.
 
-Two bit-identical executions of one shared tile body (``_delta_body``):
-
-- :func:`train_deltas_pallas` — the Pallas kernel.  Grid ``(M/bm, B/bb)``
-  with the batch axis as the reduction (innermost) grid axis, so the
-  ``(C, bm, 2F)`` output block accumulates across batch tiles and the
-  per-sample deltas exist only as ``(bb, bm, 2F)`` VMEM blocks.
-- :func:`train_deltas` — the dispatcher the ``fused`` TrainEngine calls:
-  on a compiled TPU build it invokes the kernel; in interpret mode (this
-  repo's CPU path) it runs the same body as one straight-line jitted XLA
-  computation, because the Pallas *interpreter* pays a per-grid-step
-  slicing cost that dwarfs the math on CPU (~5-15× at bench shapes).
+:func:`train_deltas` runs the delta body (``_delta_body``) and the
+class-free segment-sum as one jitted XLA computation on every platform.
+There is no Pallas kernel for this step: Mosaic has no TPU lowering for
+an in-kernel scatter-add.
 
 Delta-exactness: the Type I randomness enters as the *raw* uniform words
 (``jax.random.bits`` — the very words ``jax.random.uniform`` converts to
@@ -37,13 +30,7 @@ The reference compares ``u < p`` on ``u = (bits >> 9) · 2⁻²³``; both
 sides are exactly representable in f32, so the comparison is equivalent
 to the integer test ``(bits >> 9) < ceil(f32(p) · 2²³)``
 (:func:`uniform_threshold`) — the decisions are bitwise identical
-(property-tested in ``tests/test_train_engine.py``).  All delta
-arithmetic is int32.
-
-Padding is exact: padded batch rows carry all-zero feedback masks (their
-deltas vanish before the segment-sum; their segment id 0 receives zeros),
-padded clause rows likewise, and padded literal/class lanes are sliced
-off the output.
+(property-tested in ``tests/test_train_engine.py``).
 """
 
 from __future__ import annotations
@@ -54,13 +41,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
-__all__ = ["train_deltas", "train_deltas_pallas", "uniform_threshold",
-           "feedback_polarity_masks", "DEFAULT_BLOCK_B", "DEFAULT_BLOCK_M"]
-
-DEFAULT_BLOCK_B = 64        # batch tile (reduction axis of the segment-sum)
-DEFAULT_BLOCK_M = 128       # clause tile
+__all__ = ["train_deltas", "uniform_threshold", "feedback_polarity_masks"]
 
 
 def feedback_polarity_masks(fb_t: jax.Array, fb_n: jax.Array,
@@ -95,24 +77,23 @@ def uniform_threshold(p: float) -> int:
 
 def _delta_body(lit, bits1, bits2, inc_t, inc_n, m1_t, m2_t, m1_n, m2_n,
                 *, t_inc, t_dec):
-    """The shared tile body: per-sample Type I/II deltas, all int32.
+    """Per-sample Type I/II deltas.
 
-    lit (bb, L) {0,1}; bits1/bits2 (bb, bm, L) uint32; inc_t/inc_n
-    (bb, bm, L) {0,1}; m*_* (bb, bm) bool → (d_t, d_n), each
-    (bb, bm, L) int16 in {−1, 0, 1} (int16 keeps the delta stream half
+    lit (B, L) {0,1}; bits1/bits2 (B, M, L) uint32; inc_t/inc_n
+    (B, M, L) {0,1}; m*_* (B, M) bool → (d_t, d_n), each
+    (B, M, L) int16 in {−1, 0, 1} (int16 keeps the delta stream half
     the width of the reference's int32 one; the summed magnitude per
-    (class, clause, literal) is ≤ B ≪ 2¹⁵).  Runs identically as a
-    Pallas tile and as a full-array jnp computation.
+    (class, clause, literal) is ≤ B ≪ 2¹⁵).
     """
     # clause outputs of the addressed classes: violation-count formulation,
     # kept in int8 ({0,1} products) with an int32 reduction
-    not_lit = (1 - lit)[:, None, :]                      # (bb, 1, L) int8
+    not_lit = (1 - lit)[:, None, :]                      # (B, 1, L) int8
     cl_t = (jnp.sum(inc_t * not_lit, axis=-1, dtype=jnp.int32)
             == 0)[:, :, None]
     cl_n = (jnp.sum(inc_n * not_lit, axis=-1, dtype=jnp.int32)
             == 0)[:, :, None]
 
-    lit0 = (lit == 0)[:, None, :]                        # (bb, 1, L)
+    lit0 = (lit == 0)[:, None, :]                        # (B, 1, L)
     t_i = jnp.uint32(t_inc)
     t_d = jnp.uint32(t_dec)
 
@@ -138,106 +119,14 @@ def _delta_body(lit, bits1, bits2, inc_t, inc_n, m1_t, m2_t, m1_n, m2_n,
     return d_t, d_n
 
 
-def _train_deltas_kernel(lit_ref, b1_ref, b2_ref, it_ref, in_ref,
-                         m1t_ref, m2t_ref, m1n_ref, m2n_ref, y_ref, yn_ref,
-                         o_ref, *, t_inc, t_dec):
-    j = pl.program_id(1)
-
-    d_t, d_n = _delta_body(lit_ref[...], b1_ref[...], b2_ref[...],
-                           it_ref[...], in_ref[...], m1t_ref[...],
-                           m2t_ref[...], m1n_ref[...], m2n_ref[...],
-                           t_inc=t_inc, t_dec=t_dec)
-    cp = o_ref.shape[0]
-    bb, bm, lp = d_t.shape
-
-    # class-free scatter over this batch tile (on a compiled TPU build
-    # this reduction would become a one-hot MXU matmul; Mosaic has no
-    # efficient scatter — interpret mode runs it as plain XLA)
-    upd = jax.ops.segment_sum(d_t.reshape(bb, bm * lp), y_ref[...][:, 0],
-                              num_segments=cp)
-    upd += jax.ops.segment_sum(d_n.reshape(bb, bm * lp), yn_ref[...][:, 0],
-                               num_segments=cp)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += upd.astype(jnp.int32).reshape(cp, bm, lp)
-
-
 @functools.partial(jax.jit, static_argnames=("n_classes", "p_inc", "p_dec",
-                                             "block_b", "block_m",
-                                             "interpret"))
-def train_deltas_pallas(literals: jax.Array, bits1: jax.Array,
-                        bits2: jax.Array, inc_t: jax.Array, inc_n: jax.Array,
-                        m1_t: jax.Array, m2_t: jax.Array,
-                        m1_n: jax.Array, m2_n: jax.Array,
-                        y: jax.Array, y_neg: jax.Array, *, n_classes: int,
-                        p_inc: float, p_dec: float,
-                        block_b: int = DEFAULT_BLOCK_B,
-                        block_m: int = DEFAULT_BLOCK_M,
-                        interpret: bool = True) -> jax.Array:
-    """The Pallas kernel path of :func:`train_deltas` (same contract).
-
-    Pads every operand to tile multiples (B→``block_b``, M→``block_m``,
-    L→128 lanes, C→8), runs the ``(M/bm, B/bb)`` grid with batch-axis
-    output accumulation, and slices the padding back off.
-    """
-    b, l = literals.shape
-    m = m1_t.shape[1]
-    c = n_classes
-    bp = -(-b // block_b) * block_b
-    mp = -(-m // block_m) * block_m
-    lp = -(-l // 128) * 128
-    cp = -(-c // 8) * 8
-
-    lit = jnp.pad(literals, ((0, bp - b), (0, lp - l)))
-    b1 = jnp.pad(bits1, ((0, bp - b), (0, mp - m), (0, lp - l)))
-    b2 = jnp.pad(bits2, ((0, bp - b), (0, mp - m), (0, lp - l)))
-    it = jnp.pad(inc_t, ((0, bp - b), (0, mp - m), (0, lp - l)))
-    in_ = jnp.pad(inc_n, ((0, bp - b), (0, mp - m), (0, lp - l)))
-    masks = [jnp.pad(mm, ((0, bp - b), (0, mp - m)))
-             for mm in (m1_t, m2_t, m1_n, m2_n)]
-    yp = jnp.pad(y, (0, bp - b)).reshape(bp, 1)
-    ynp = jnp.pad(y_neg, (0, bp - b)).reshape(bp, 1)
-
-    out = pl.pallas_call(
-        functools.partial(_train_deltas_kernel,
-                          t_inc=uniform_threshold(p_inc),
-                          t_dec=uniform_threshold(p_dec)),
-        grid=(mp // block_m, bp // block_b),
-        in_specs=[
-            pl.BlockSpec((block_b, lp), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_b, block_m, lp), lambda i, j: (j, i, 0)),
-            pl.BlockSpec((block_b, block_m, lp), lambda i, j: (j, i, 0)),
-            pl.BlockSpec((block_b, block_m, lp), lambda i, j: (j, i, 0)),
-            pl.BlockSpec((block_b, block_m, lp), lambda i, j: (j, i, 0)),
-            pl.BlockSpec((block_b, block_m), lambda i, j: (j, i)),
-            pl.BlockSpec((block_b, block_m), lambda i, j: (j, i)),
-            pl.BlockSpec((block_b, block_m), lambda i, j: (j, i)),
-            pl.BlockSpec((block_b, block_m), lambda i, j: (j, i)),
-            pl.BlockSpec((block_b, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_b, 1), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((cp, block_m, lp), lambda i, j: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((cp, mp, lp), jnp.int32),
-        interpret=interpret,
-    )(lit, b1, b2, it, in_, *masks, yp, ynp)
-    return out[:c, :m, :l]
-
-
-@functools.partial(jax.jit, static_argnames=("n_classes", "p_inc", "p_dec",
-                                             "block_b", "block_m",
-                                             "interpret", "widen"))
+                                             "widen"))
 def train_deltas(literals: jax.Array, bits1: jax.Array, bits2: jax.Array,
                  inc_t: jax.Array, inc_n: jax.Array,
                  m1_t: jax.Array, m2_t: jax.Array,
                  m1_n: jax.Array, m2_n: jax.Array,
                  y: jax.Array, y_neg: jax.Array, *, n_classes: int,
-                 p_inc: float, p_dec: float,
-                 block_b: int = DEFAULT_BLOCK_B,
-                 block_m: int = DEFAULT_BLOCK_M,
-                 interpret: bool = True, widen: bool = True) -> jax.Array:
+                 p_inc: float, p_dec: float, widen: bool = True) -> jax.Array:
     """Fused Type I/II feedback deltas, summed per class over the batch.
 
     literals (B, L) {0,1} int8; bits1/bits2 (B, M, L) uint32 — the raw
@@ -253,23 +142,12 @@ def train_deltas(literals: jax.Array, bits1: jax.Array, bits2: jax.Array,
     probability 1/s; both become exact integer thresholds on the uniform
     bits (:func:`uniform_threshold`).
 
-    ``interpret=False`` (real TPU) runs :func:`train_deltas_pallas`;
-    interpret mode runs the identical body as straight-line XLA (the
-    Pallas interpreter's per-grid-step slicing costs more than the math
-    on CPU).  Both paths are bit-identical.
-
     ``widen=False`` returns the int16 per-element sums directly (exact
     while 2B < 2¹⁵ — a literal can collect at most one target and one
     negative contribution per row) instead of widening to int32 — the
     sharded trainer reduce-scatters the partials across shards first and
     widens after, halving the collective payload.
     """
-    if not interpret:
-        upd = train_deltas_pallas(
-            literals, bits1, bits2, inc_t, inc_n, m1_t, m2_t, m1_n, m2_n,
-            y, y_neg, n_classes=n_classes, p_inc=p_inc, p_dec=p_dec,
-            block_b=block_b, block_m=block_m, interpret=False)
-        return upd if widen else upd.astype(jnp.int16)
     d_t, d_n = _delta_body(literals, bits1, bits2, inc_t, inc_n,
                            m1_t, m2_t, m1_n, m2_n,
                            t_inc=uniform_threshold(p_inc),

@@ -44,7 +44,7 @@ State lifecycle (docs/operations.md is the operator runbook):
         --checkpoint-dir /tmp/tm-ckpt --restore
 
 ``--checkpoint-every N`` snapshots ``(version, state, key-chain cursor,
-train backend + autotune picks)`` every N applied updates off the worker
+train backend + its opts)`` every N applied updates off the worker
 thread (``--checkpoint-keep`` newest retained); ``--restore`` resumes
 the deterministic update chain bit-exactly from the newest valid step.
 ``--probe-every N`` scores a held-out teacher-labeled probe stream every
@@ -169,8 +169,9 @@ async def _stats_printer(server, every: float) -> None:
 
 
 async def _label_feeder(server, pool, labels, *, rate: float, batch: int,
-                        rng) -> None:
-    """Offer ``rate`` labeled batches/s (Poisson) until cancelled.
+                        rng, fed: dict) -> None:
+    """Offer ``rate`` labeled batches/s (Poisson) until cancelled,
+    counting them in ``fed["batches"]``.
 
     Fire-and-forget: awaiting each update would cap the offered rate at
     update throughput; instead pending futures accumulate against the
@@ -186,6 +187,7 @@ async def _label_feeder(server, pool, labels, *, rate: float, batch: int,
         rows = rng.integers(0, len(pool), batch)
         task = asyncio.ensure_future(
             server.submit_labeled(pool[rows], labels[rows]))
+        fed["batches"] += 1
         pending.add(task)
 
         def _done(t: asyncio.Task) -> None:
@@ -266,9 +268,9 @@ async def _fleet_stats_printer(fleet, every: float) -> None:
               flush=True)
 
 
-async def _run_fleet(args) -> None:
+async def _run_fleet(args) -> list[str]:
     """``--models`` mode: serve a manifest of named models as a fleet,
-    splitting the requested traffic across tenants."""
+    splitting the requested traffic across tenants → failure messages."""
     from repro.serve import ServePolicy, TMFleet, closed_loop, open_loop
 
     specs = _load_manifest(args.models, args)
@@ -344,14 +346,17 @@ async def _run_fleet(args) -> None:
               f"{cache['superseded']} superseded  "
               f"(size {cache['size']}/{cache['maxsize']}, "
               f"{cache['bytes']} bytes)")
+    errors = sum(m["errors_total"] for m in fleet.stats()["models"].values())
+    return [f"{errors} failed requests or updates"] if errors else []
 
 
-async def _run(args) -> None:
+async def _run(args) -> list[str]:
+    """Serve (and learn) for ``--duration`` → failure messages, empty
+    when the run was clean."""
     from repro.serve import ServePolicy, TMServer, closed_loop, open_loop
 
     if args.models:
-        await _run_fleet(args)
-        return
+        return await _run_fleet(args)
 
     cfg, state = build_tm(args.classes, args.clauses, args.features,
                           density=args.density, seed=args.seed)
@@ -416,6 +421,7 @@ async def _run(args) -> None:
         version = server.restore()
         print(f"restored from {args.checkpoint_dir} at state version "
               f"{version} (resuming the deterministic update chain)")
+    fed = {"batches": 0}
     async with server:
         print(f"TM C={cfg.n_classes} M={cfg.n_clauses} F={cfg.n_features} "
               f"density={args.density}  buckets={server.buckets}")
@@ -433,7 +439,8 @@ async def _run(args) -> None:
             feeder = asyncio.ensure_future(
                 _label_feeder(server, pool, labels, rate=args.label_rate,
                               batch=args.label_batch,
-                              rng=np.random.default_rng(args.seed + 3)))
+                              rng=np.random.default_rng(args.seed + 3),
+                              fed=fed))
         rejects = []
         slo = dict(deadline_us=args.deadline_us or None,
                    deadline_fraction=args.priority_mix,
@@ -493,12 +500,25 @@ async def _run(args) -> None:
               f"misses  {cache['evictions']} evictions  "
               f"(size {cache['size']}/{cache['maxsize']})")
 
+    # read after stop(): it drains the queued updates, whose failures
+    # count too
+    s = server.stats()
+    failures = []
+    if s["errors"]:
+        failures.append(f"{s['errors']} failed requests or updates")
+    if fed["batches"] and not s["updates"]:
+        failures.append(f"--train-backend {args.train_backend} applied none "
+                        f"of {fed['batches']} labeled batches")
+    return failures
+
 
 def main(argv: list[str] | None = None) -> None:
     """CLI entry point: parse flags, stand up the server, drive traffic
     (see the module docstring for the flag reference and the lifecycle
     workflows; docs/operations.md for the operator runbook).  ``argv``
-    overrides ``sys.argv`` (the smoke tests drive it in-process)."""
+    overrides ``sys.argv`` (the smoke tests drive it in-process).  Exits
+    with status 1 when any request or update failed, or when
+    the label feeder offered batches and none was applied."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--classes", type=int, default=10)
     ap.add_argument("--clauses", type=int, default=100)
@@ -609,7 +629,12 @@ def main(argv: list[str] | None = None) -> None:
             os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
     if args.mesh and args.mesh < 1:
         raise SystemExit("--mesh must be >= 1")
-    asyncio.run(_run(args))
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    failures = asyncio.run(_run(args))
+    if failures:
+        print("tm_serve: FAILED: " + "; ".join(failures), file=sys.stderr)
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

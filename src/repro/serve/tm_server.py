@@ -796,14 +796,16 @@ class TMServer:
         self._publish(version, TMState(ta=jnp.asarray(tree["ta"])))
         if extra.get("has_cursor"):
             from repro.engine import get_train_engine
-            from repro.engine.train import import_key_cursor
+            from repro.engine.train import (RETIRED_TRAIN_OPTS,
+                                            import_key_cursor)
             backend = extra.get("train_backend")
             if backend:
                 # the checkpoint's backend + autotune picks win — even
                 # when the backend name matches the constructor's, the
                 # saved opts override this host's autotune cache:
                 # restore means resume *that* run, not a local retune
-                topts = dict(extra.get("train_opts", {}))
+                topts = {k: v for k, v in extra.get("train_opts", {}).items()
+                         if k not in RETIRED_TRAIN_OPTS}
                 if backend == "sharded":
                     # mesh size is elastic: the override mesh wins, and
                     # a recorded size this host can't build clamps to
@@ -1170,6 +1172,10 @@ class TMServer:
                     continue
                 update_running = (self._update_task is not None
                                   and not self._update_task.done())
+                if self._pending_updates and not update_running:
+                    # depth 1 ran one queued update inline; the next
+                    # must not wait for an arrival that may never come
+                    continue
                 if (self._stop_seen and self._queue.empty()
                         and not self._pending_updates
                         and not update_running):

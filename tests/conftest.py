@@ -20,6 +20,8 @@ Property-suite hygiene, both flavors:
 import os
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # Simulate an 8-device host so the multi-host suites (test_multihost.py,
@@ -73,3 +75,21 @@ def pytest_report_header(config):
                 f"(reproduce with --hypothesis-seed={seed})")
     from hypothesis import settings
     return f"hypothesis: real, profile={settings._current_profile}"
+
+
+@pytest.fixture
+def isolated_compile_cache(tmp_path, monkeypatch):
+    """``$JAX_COMPILATION_CACHE_DIR`` pointed at a scratch directory for a
+    test that turns on the persistent compile cache (the entry points do);
+    JAX's cache settings are restored afterwards, so the rest of the
+    worker's tests compile exactly as before."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax"))
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs")}
+    yield tmp_path / "jax"
+    for k, v in saved.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()

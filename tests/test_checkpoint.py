@@ -63,17 +63,18 @@ def test_gc_keep_never_prunes_in_flight_step(tmp_path, monkeypatch):
     in_shard_write = threading.Event()
     release = threading.Event()
     real_savez = np.savez
-    blocked_thread = []
+    test_thread = threading.current_thread()
 
     def slow_savez(*args, **kwargs):
-        if threading.current_thread() in blocked_thread:
+        # block the writer thread only; it may reach here before
+        # save_async has even returned its handle
+        if threading.current_thread() is not test_thread:
             in_shard_write.set()
             assert release.wait(timeout=30)
         return real_savez(*args, **kwargs)
 
     monkeypatch.setattr(np, "savez", slow_savez)
     t = ckpt.save_async(d, 5, _tree(50), extra={"gen": "new"})
-    blocked_thread.append(t)
     assert in_shard_write.wait(timeout=30)
 
     # while step 5's new write is in flight, retention must leave it

@@ -102,12 +102,22 @@ def test_time_domain_aux_and_physical_device():
     best = np.asarray(res.class_sums).max(-1)
     lat = np.asarray(res.aux["latency_ps"])
     assert np.corrcoef(best, lat)[0, 1] < 0
-    # a physical device (variation, no skew) still mostly agrees
+    # a physical device (variation, no skew) agrees with the ideal
+    # arbiter.  Its variation (sigma 2 ps per element) is far below one
+    # vote's delay step (d_high - d_low = 233 ps), so on every row it
+    # picks a class with the maximal sum; on exactly tied sums the race
+    # is decided by the device's own element offsets, not by the ideal
+    # arbiter's lowest index, so a tie won by any tied class agrees
     pdl = PDLConfig(sigma_elem=2.0, sigma_noise=0.0)
     dev = make_device(pdl, cfg.n_classes, cfg.n_clauses, jax.random.key(1))
     phys = get_engine("time_domain", cfg, st, pdl=pdl, device=dev).infer(lits)
-    agree = np.mean(np.asarray(phys.prediction == res.prediction))
-    assert agree > 0.8
+    sums = np.asarray(res.class_sums)
+    won = sums[np.arange(len(sums)), np.asarray(phys.prediction)]
+    np.testing.assert_array_equal(won, sums.max(-1))
+    unique = (sums == sums.max(-1, keepdims=True)).sum(-1) == 1
+    assert unique.any()
+    np.testing.assert_array_equal(np.asarray(phys.prediction)[unique],
+                                  np.asarray(res.prediction)[unique])
 
 
 @pytest.mark.parametrize("backend", ALL_BACKENDS)

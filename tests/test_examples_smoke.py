@@ -11,6 +11,8 @@ checkpoint continues bit-exactly against the uninterrupted run.
 import importlib.util
 import pathlib
 
+import pytest
+
 _EXAMPLES = pathlib.Path(__file__).resolve().parents[1] / "examples"
 
 
@@ -44,7 +46,7 @@ def test_checkpoint_serving_example_bit_exact():
     assert out["version"] == 6 and out["n_predictions"] == 6
 
 
-def test_tm_serve_launcher_deadline_flags(capsys):
+def test_tm_serve_launcher_deadline_flags(capsys, isolated_compile_cache):
     """The serving launcher runs end to end with SLO traffic: deadline +
     priority-mix flags, pipelined dispatch, and the deadline summary
     line (the docs' quickstart command can't rot)."""
@@ -58,3 +60,65 @@ def test_tm_serve_launcher_deadline_flags(capsys):
     assert "deadline 500000us" in out
     assert "mix 0.50" in out
     assert "req/s" in out
+
+
+def test_tm_serve_launcher_exits_nonzero_on_failed_updates(
+        capsys, monkeypatch, isolated_compile_cache):
+    """A train step that passes warmup and then fails on every labeled
+    update must fail the run: exit status 1, with both the error count
+    and the stuck state version named — not a clean exit at version 0."""
+    from repro.engine.train import PackedTrainEngine
+    from repro.launch.tm_serve import main
+    real_step = PackedTrainEngine.step
+    calls = []
+
+    def flaky_step(self, *args):
+        calls.append(1)
+        if len(calls) > 1:                  # the warmup step passes
+            raise RuntimeError("injected train-step failure")
+        return real_step(self, *args)
+
+    monkeypatch.setattr(PackedTrainEngine, "step", flaky_step)
+    with pytest.raises(SystemExit) as exc:
+        main(["--classes", "3", "--clauses", "16", "--features", "12",
+              "--max-batch", "8", "--backend", "oracle", "--rate", "200",
+              "--duration", "0.5", "--stats-every", "0.2",
+              "--train-backend", "packed", "--label-rate", "40",
+              "--label-batch", "4"])
+    assert exc.value.code == 1
+    assert len(calls) > 1
+    err = capsys.readouterr().err
+    assert "failed requests or updates" in err
+    assert "applied none of" in err
+
+
+def test_tm_serve_launcher_learns_and_exits_cleanly(
+        capsys, isolated_compile_cache):
+    """The same serve-while-learn run with a working trainer returns
+    normally, has climbed past version 0, and kept its compile cache in
+    ``$JAX_COMPILATION_CACHE_DIR``."""
+    import jax
+    from repro.launch.tm_serve import main
+    main(["--classes", "3", "--clauses", "16", "--features", "12",
+          "--max-batch", "8", "--backend", "oracle", "--rate", "200",
+          "--duration", "0.5", "--stats-every", "0.2",
+          "--train-backend", "packed", "--label-rate", "40",
+          "--label-batch", "4"])
+    out = capsys.readouterr().out
+    assert "state_version=" in out and "state_version=0 " not in out
+    assert jax.config.jax_compilation_cache_dir == str(
+        isolated_compile_cache)
+
+
+def test_tm_serve_launcher_no_labels_offered_is_clean(
+        capsys, isolated_compile_cache):
+    """A run too short for the Poisson label feeder to offer a batch
+    applied no update, yet nothing failed: it returns normally."""
+    from repro.launch.tm_serve import main
+    main(["--classes", "3", "--clauses", "16", "--features", "12",
+          "--max-batch", "8", "--backend", "oracle", "--rate", "200",
+          "--duration", "0.05", "--stats-every", "0.2",
+          "--train-backend", "packed", "--label-rate", "0.001",
+          "--label-batch", "4"])
+    out = capsys.readouterr().out
+    assert "state_version=0 " in out

@@ -147,6 +147,45 @@ def test_restore_adopts_checkpoint_backend_and_enables_training(tmp_path):
     np.testing.assert_array_equal(ta_b, np.asarray(s.ta))
 
 
+@pytest.mark.parametrize("backend", ["fused", "sparse", "sharded"])
+def test_restore_ignores_retired_tile_opts(backend, tmp_path):
+    """Checkpoints written while the fused/sparse/sharded trainers took
+    ``block_b``/``block_m`` still carry them in ``train_opts``: restore
+    drops the two keys and resumes the same chain bit-exactly."""
+    cfg, state = _tm(seed=8)
+    batches = _batches(cfg, 4, 8, seed=9)
+    old, d = str(tmp_path / "written"), str(tmp_path / "older")
+
+    async def phase1():
+        async with TMServer(cfg, state, ServePolicy(max_batch=8),
+                            train_backend=backend, train_seed=13) as srv:
+            for b in batches[:2]:
+                await srv.submit_labeled(*b)
+        srv.checkpoint(old)
+
+    asyncio.run(phase1())
+    step, tree, extra = ckpt.restore_tm_lifecycle(old)
+    extra["train_opts"].update(block_b=64, block_m=128)
+    ckpt.save(d, step, tree, extra=extra)
+
+    async def phase2():
+        srv = TMServer(cfg, state, ServePolicy(max_batch=8))
+        assert srv.restore(d) == 2
+        async with srv:
+            for b in batches[2:]:
+                await srv.submit_labeled(*b)
+            return np.asarray(srv.state.ta), srv.state_version
+
+    ta_b, v_b = asyncio.run(phase2())
+    assert v_b == 4
+    eng = get_train_engine("fused", cfg)
+    chain, s = jax.random.key(13), state
+    for lits, labels in batches:
+        chain, k = jax.random.split(chain)
+        s = eng.step(s, k, jnp.asarray(lits), jnp.asarray(labels))
+    np.testing.assert_array_equal(ta_b, np.asarray(s.ta))
+
+
 def test_restore_validation(tmp_path):
     cfg, state = _tm()
     d = str(tmp_path / "ck")

@@ -177,6 +177,43 @@ def test_version_pinning_survives_pipelined_updates(n_updates, n_predicts,
         assert matched, "response matches no committed state version"
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+def test_queued_updates_all_apply_without_more_arrivals(depth):
+    """Labeled batches that queue up together each get applied with no
+    later arrival to wake the scheduler.  At depth 1 an update runs
+    inline, so a second queued one used to sit until the next request
+    arrived — forever, when the client was awaiting the first."""
+    cfg, state = _learn_tm(seed=7)
+    lits, labels = _stream(cfg, 24, seed=3)
+    batches = [(lits[8 * i:8 * i + 8], labels[8 * i:8 * i + 8])
+               for i in range(3)]
+    expected = _expected_chain(cfg, state, batches, backend="packed",
+                               seed=5)
+
+    async def go():
+        srv = TMServer(cfg, state,
+                       ServePolicy(max_batch=8, backend="oracle",
+                                   pipeline_depth=depth),
+                       train_backend="packed", train_seed=5)
+        await srv.start()
+        await srv.warmup(train_batches=(8,))
+        updates = asyncio.gather(*[srv.submit_labeled(*b) for b in batches])
+        done, _ = await asyncio.wait({updates}, timeout=60)
+        if not done:
+            # stop() would wait on the stuck update too: cancel instead
+            updates.cancel()
+            srv._task.cancel()
+            return None, None
+        await srv.stop()
+        return updates.result(), srv.state
+
+    versions, final = asyncio.run(go())
+    assert versions is not None, "queued labeled updates never applied"
+    assert sorted(versions) == [1, 2, 3]
+    np.testing.assert_array_equal(np.asarray(final.ta),
+                                  np.asarray(expected[-1].ta))
+
+
 def test_stop_mid_pipeline_drains_inflight():
     """stop() while batches are queued and in flight: every accepted
     request resolves (exactly once), nothing hangs, and the pipeline

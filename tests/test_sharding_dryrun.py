@@ -95,12 +95,8 @@ from repro.models.model import LM
 from repro.launch.dryrun import _lower
 from repro.roofline.analysis import collective_bytes
 
-# axis_types only exists on newer jax (>=0.5); explicit-Auto and the
-# legacy default behave identically for this dry-run, so gate on presence
-mesh_kwargs = {}
-if hasattr(jax.sharding, "AxisType"):
-    mesh_kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-mesh = jax.make_mesh((2, 2), ("data", "model"), **mesh_kwargs)
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 out = {}
 for arch in ["tinyllama-1.1b", "llama4-scout-17b-a16e", "mamba2-130m"]:
     cfg = dataclasses.replace(reduced(get_config(arch)), name=arch)
@@ -194,3 +190,30 @@ def test_sharded_engine_bit_exact_all_backends(backend, batch):
                                   np.asarray(ref.prediction))
     np.testing.assert_array_equal(np.asarray(res.class_sums),
                                   np.asarray(ref.class_sums))
+
+
+@pytest.mark.parametrize("backend", ["oracle", "sparse_csr"])
+def test_sharded_engine_compiles_once_per_shape(backend):
+    """Serving the same bucket again reuses the compiled program: every
+    mesh-served batch used to re-lower and recompile its ``shard_map``."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src.dispatch import BACKEND_COMPILE_EVENT
+    from repro.engine import get_engine
+    cfg, st = _random_tm(4, 10, 12, seed=9)
+    sharded = get_engine(backend, cfg, st, shard_batch=True, cache=False)
+    lits = jnp.ones((16, cfg.n_literals), jnp.int8)
+    jax.block_until_ready(sharded.infer(lits))
+    compiles = []
+
+    def listener(event, duration_secs, **_):
+        if event == BACKEND_COMPILE_EVENT:
+            compiles.append(duration_secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        for _ in range(3):
+            jax.block_until_ready(sharded.infer(lits))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert compiles == []

@@ -195,7 +195,7 @@ def test_sparse_backend_registered_with_opts():
     assert "sparse" in available_train_backends()
     cfg = TMConfig(n_classes=2, n_clauses=4, n_features=6)
     eng = get_train_engine("sparse", cfg, cache=False, k_slack=16,
-                           rebuild_threshold=0.5, block_b=32, block_m=32)
+                           rebuild_threshold=0.5)
     opts = train_engine_opts(eng)
     assert opts["k_slack"] == 16 and opts["rebuild_threshold"] == 0.5
     assert eng.layout_stats() is None            # no concrete step yet

@@ -133,30 +133,6 @@ def test_delta_parity_property(c, m, f, batch, density, seed):
         _assert_state_equal(got, ref)
 
 
-def test_pallas_kernel_path_matches_dispatcher():
-    """The real Pallas kernel (tiled grid, interpret mode) must equal the
-    straight-line jnp path the CPU dispatcher uses — this is the TPU
-    path's logic check (BlockSpecs, batch-axis accumulation, padding)."""
-    from repro.kernels.train_fused import train_deltas, train_deltas_pallas
-    rng = np.random.default_rng(13)
-    b, m, L, c = 21, 11, 37, 5
-    x = jnp.asarray(rng.integers(0, 2, (b, L), dtype=np.int8))
-    bits1 = jnp.asarray(rng.integers(0, 2**32, (b, m, L), dtype=np.uint32))
-    bits2 = jnp.asarray(rng.integers(0, 2**32, (b, m, L), dtype=np.uint32))
-    inc_t = jnp.asarray(rng.integers(0, 2, (b, m, L), dtype=np.int8))
-    inc_n = jnp.asarray(rng.integers(0, 2, (b, m, L), dtype=np.int8))
-    masks = [jnp.asarray(rng.integers(0, 2, (b, m)).astype(bool))
-             for _ in range(4)]
-    y = jnp.asarray(rng.integers(0, c, (b,), dtype=np.int32))
-    yn = jnp.asarray((np.asarray(y) + 1) % c, dtype=jnp.int32)
-    kw = dict(n_classes=c, p_inc=2.9 / 3.9, p_dec=1 / 3.9)
-    ref = train_deltas(x, bits1, bits2, inc_t, inc_n, *masks, y, yn, **kw)
-    for bb, bm in [(8, 4), (32, 16), (4, 2)]:
-        got = train_deltas_pallas(x, bits1, bits2, inc_t, inc_n, *masks,
-                                  y, yn, block_b=bb, block_m=bm, **kw)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-
-
 def test_uniform_threshold_equivalence():
     """(bits >> 9) < uniform_threshold(p)  ⟺  uniform(bits) < p, exactly."""
     from repro.kernels.train_fused import uniform_threshold
@@ -196,25 +172,29 @@ def test_train_engine_cache():
 
 
 def test_train_autotune_lookup_applied(tmp_path, monkeypatch):
-    """get_train_engine picks tuned tiles from the train:fused cache key;
-    explicit opts win."""
+    """Trainers take no tile opts: an autotune cache that still holds
+    ``train:fused`` tile entries from older sweeps is ignored, and the
+    engine builds, steps and persists only its real options."""
     import json
     from repro.engine import autotune
+    from repro.engine.train import train_engine_opts
     clear_train_engine_cache()
-    cfg = TMConfig(n_classes=3, n_clauses=10, n_features=12)
-    key = autotune.shape_key("train:fused", cfg)
+    cfg, st, lits, y = _random_tm(3, 10, 12, seed=29)
     path = tmp_path / "autotune.json"
-    path.write_text(json.dumps(
-        {"best": {key: {"block_b": 32, "block_m": 32, "stale_opt": 1}}}))
+    path.write_text(json.dumps({"best": {
+        autotune.shape_key(f"train:{b}", cfg): {"block_b": 32,
+                                                "block_m": 32}
+        for b in ("fused", "sparse", "sharded")}}))
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(path))
-    assert autotune.lookup("train:fused", cfg) == {"block_b": 32,
-                                                   "block_m": 32}
-    eng = get_train_engine("fused", cfg, cache=False)
-    assert eng._blocks == (32, 32)
-    eng = get_train_engine("fused", cfg, cache=False, block_b=64)
-    assert eng._blocks == (64, 32)
-    # untuned backend → no opts, no error
-    assert autotune.lookup("train:reference", cfg) == {}
+    key = jax.random.key(6)
+    for backend in ("fused", "sparse", "sharded"):
+        assert autotune.lookup(f"train:{backend}", cfg) == {}
+        eng = get_train_engine(backend, cfg, cache=False)
+        assert "block_b" not in train_engine_opts(eng)
+        _assert_state_equal(eng.step(st, key, lits, y),
+                            train_step(cfg, st, key, lits, y))
+    with pytest.raises(TypeError):
+        get_train_engine("fused", cfg, cache=False, block_b=64)
 
 
 def test_training_converges_through_engines():
