@@ -115,6 +115,14 @@ completion before the next opens, updates quiesce predicts).
   as the state advances and surfaces rolling accuracy/regression deltas
   in :meth:`stats`.  Operator procedures: docs/operations.md.
 
+- tracing — every stage above records a ``jax.profiler`` span (the
+  names in :data:`SPANS`), so a profiler trace of a live server shows
+  the host's stages on the same clock as the device's ops; each batch's
+  spans carry its sequence number as ``batch=<n>``.  With the profiler
+  off a span costs about a microsecond and adds no device work.
+  ``stats()['queue_wait']`` counts the time requests waited between
+  arrival and dispatch.  What each span covers: docs/operations.md.
+
 Ordering caveat: a single client with *multiple concurrently
 outstanding* requests carrying different deadlines/priorities may see
 them complete in EDF order rather than submission order — sequential
@@ -139,6 +147,7 @@ import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 
+import jax
 import numpy as np
 
 from repro.core.tm import TMConfig, TMState, include_mask
@@ -150,10 +159,30 @@ from repro.engine.sparse import IncrementalEll
 
 from .loadgen import DeadlineExceeded, percentiles_ms
 
-__all__ = ["ServePolicy", "TMServer", "DeadlineExceeded", "bucket_for",
-           "default_buckets", "route_buckets"]
+__all__ = ["ServePolicy", "TMServer", "DeadlineExceeded", "SPANS",
+           "AWAIT_SPANS", "bucket_for", "default_buckets", "route_buckets"]
 
 _STOP = object()        # queue sentinel: wakes the scheduler for shutdown
+
+# A host span in a profiler trace.  Keyword metadata is formatted only
+# while a trace is being taken, so with the profiler off a span costs
+# about a microsecond.
+_span = jax.profiler.TraceAnnotation
+
+#: The server's profiler spans, in pipeline order (docs/operations.md):
+#: ``tm.idle`` (scheduler waiting with nothing pending), ``tm.coalesce``,
+#: ``tm.pipeline_wait``, ``tm.assemble`` (stage A, event loop);
+#: ``tm.stageB.dispatch``, ``tm.stageB.sync``, ``tm.stageB.copy``
+#: (stage B, worker thread); ``tm.fanout`` (stage C); ``tm.publish``
+#: (state swap and serving refresh) and ``tm.train_step`` (training
+#: thread).  A batch's spans carry ``batch=<n>``, a publish ``version=<n>``.
+SPANS = ("tm.idle", "tm.coalesce", "tm.pipeline_wait", "tm.assemble",
+         "tm.stageB.dispatch", "tm.stageB.sync", "tm.stageB.copy",
+         "tm.fanout", "tm.publish", "tm.train_step")
+#: The spans of :data:`SPANS` that cover the event loop awaiting work
+#: (an arrival, the coalescing budget, a pipeline slot); every other
+#: span covers work.
+AWAIT_SPANS = ("tm.idle", "tm.coalesce", "tm.pipeline_wait")
 
 
 def default_buckets(max_batch: int) -> tuple[int, ...]:
@@ -436,7 +465,6 @@ class TMServer:
         self._train_backend = train_backend
         self._train_pool: ThreadPoolExecutor | None = None
         if train_backend is not None:
-            import jax
             from repro.engine import get_train_engine
             # a mesh-configured server shards its training too: the
             # sharded backend takes the mesh directly (Mesh is hashable,
@@ -498,6 +526,7 @@ class TMServer:
         self._update_task: asyncio.Task | None = None
         self._fanout_task: asyncio.Task | None = None
         self._seq = 0
+        self._batch_seq = 0               # the batch= number of the spans
         self._next_slot = 0
         self._asm_buffers: list[np.ndarray | None] = \
             [None] * self.policy.pipeline_depth
@@ -528,6 +557,9 @@ class TMServer:
         self._n_admission_rejects = 0
         self._n_expired_drops = 0
         self._n_slack_shed_batches = 0
+        # requests dispatched and their summed arrival→dispatch wait
+        self._n_waited = 0
+        self._wait_s = 0.0
         # tier counters: shed decisions are per batch; escalation splits
         # are per row, reported by any engine whose aux carries an
         # "escalated" mask (the cascade, shed or routed)
@@ -549,20 +581,21 @@ class TMServer:
         then re-resolves serving against the new state
         (:meth:`_refresh_serving`) — routes, sparse layout, and the
         superseded state's cached engines."""
-        with self._mu:
-            prev = getattr(self, "_current", None)
-            self._current = (version, state)
-            self._history.append((version, state))
-        self._refresh_serving(
-            state, superseded=prev[1] if prev is not None else None)
-        if self._on_publish is not None:
-            try:
-                self._on_publish(version, state)
-            except Exception:
-                # a broken observer must not poison the publish/update
-                # path — count it and keep serving the new state
-                with self._mu:
-                    self._n_publish_hook_errors += 1
+        with _span("tm.publish", version=version):
+            with self._mu:
+                prev = getattr(self, "_current", None)
+                self._current = (version, state)
+                self._history.append((version, state))
+            self._refresh_serving(
+                state, superseded=prev[1] if prev is not None else None)
+            if self._on_publish is not None:
+                try:
+                    self._on_publish(version, state)
+                except Exception:
+                    # a broken observer must not poison the publish/update
+                    # path — count it and keep serving the new state
+                    with self._mu:
+                        self._n_publish_hook_errors += 1
 
     def publish(self, state: TMState) -> int:
         """Swap in ``state`` as a new version (bumped by one) → version.
@@ -774,7 +807,6 @@ class TMServer:
         if directory is None:
             raise ValueError("no checkpoint directory: pass directory= or "
                              "construct TMServer with checkpoint_dir=")
-        import jax
         import jax.numpy as jnp
         from repro import checkpoint as ckpt
         if mesh is not None:
@@ -914,7 +946,6 @@ class TMServer:
         probe eval doesn't stall the worker thread on XLA.  The warmup
         step's result is discarded; the served state is untouched.
         """
-        import jax
         loop = asyncio.get_running_loop()
         zeros = np.zeros((1, self.cfg.n_literals), np.int8)
         buckets = list(self.buckets)
@@ -1182,9 +1213,10 @@ class TMServer:
                     break
                 # idle: wake on the next arrival, or on the in-flight
                 # update finishing (its successor may be waiting)
-                await self._next_arrival(
-                    None, extra=self._update_task if update_running
-                    else None)
+                with _span("tm.idle"):
+                    await self._next_arrival(
+                        None, extra=self._update_task if update_running
+                        else None)
         finally:
             t, self._get_task = self._get_task, None
             if t is not None:
@@ -1226,25 +1258,29 @@ class TMServer:
         """Open a batch at the EDF head and coalesce until full, closed,
         or out of wait budget — then hand it to stage B."""
         policy = self.policy
-        first = self._pop_head()
-        batch, rows = [first], first.n
-        deadline = time.monotonic() + policy.max_wait_us * 1e-6
-        while rows < policy.max_batch:
-            self._drain_queue()
-            nxt = self._pop_head(version=first.version,
-                                 max_rows=policy.max_batch - rows)
-            if nxt is not None:
-                batch.append(nxt)
-                rows += nxt.n
-                continue
-            if self._pending or self._pending_updates or self._stop_seen:
-                # the head exists but cannot join (version cut / row
-                # overflow), or an update/stop wants the floor: close
-                break
-            timeout = deadline - time.monotonic()
-            if timeout <= 0 or not await self._next_arrival(timeout):
-                break
-        await self._dispatch_batch(batch, rows)
+        self._batch_seq += 1
+        seq = self._batch_seq
+        with _span("tm.coalesce", batch=seq):
+            first = self._pop_head()
+            batch, rows = [first], first.n
+            deadline = time.monotonic() + policy.max_wait_us * 1e-6
+            while rows < policy.max_batch:
+                self._drain_queue()
+                nxt = self._pop_head(version=first.version,
+                                     max_rows=policy.max_batch - rows)
+                if nxt is not None:
+                    batch.append(nxt)
+                    rows += nxt.n
+                    continue
+                if (self._pending or self._pending_updates
+                        or self._stop_seen):
+                    # the head exists but cannot join (version cut / row
+                    # overflow), or an update/stop wants the floor: close
+                    break
+                timeout = deadline - time.monotonic()
+                if timeout <= 0 or not await self._next_arrival(timeout):
+                    break
+        await self._dispatch_batch(batch, rows, seq)
 
     def _assemble(self, batch: list[_Request], rows: int, bucket: int,
                   slot: int) -> np.ndarray:
@@ -1267,31 +1303,39 @@ class TMServer:
         buf[off:bucket] = 0          # neutral padding rows
         return buf[:bucket]
 
-    async def _dispatch_batch(self, batch: list[_Request], rows: int
-                              ) -> None:
+    async def _dispatch_batch(self, batch: list[_Request], rows: int,
+                              seq: int) -> None:
         """Assemble (stage A) and launch compute (stage B), bounded at
         ``pipeline_depth`` in flight; completion metadata goes to the
-        FIFO that stage C fans out from."""
-        await self._sem.acquire()
-        slot = self._next_slot
-        self._next_slot = (slot + 1) % self.policy.pipeline_depth
-        bucket = bucket_for(rows, self.buckets)
-        lits = self._assemble(batch, rows, bucket, slot)
-        # shed decision at dispatch time: backlog depth (arrivals are
-        # outpacing compute) OR slack exhaustion (the tightest deadline
-        # in the batch is inside the bucket's expected service time)
-        slack_shed = False
-        if self.policy.shed_backend is not None:
-            deadlines = [r.deadline for r in batch if r.deadline is not None]
-            if deadlines:
-                ewma = self._svc.ewma(bucket)
-                slack_shed = (ewma is not None and
-                              min(deadlines) - time.monotonic() < ewma)
-        shed = (self.policy.shed_backend is not None
-                and (self._qdepth() >= self.policy.shed_qdepth
-                     or slack_shed))
+        FIFO that stage C fans out from.  ``seq`` is the batch's
+        sequence number, carried by its spans."""
+        with _span("tm.pipeline_wait", batch=seq):
+            await self._sem.acquire()
+        with _span("tm.assemble", batch=seq):
+            slot = self._next_slot
+            self._next_slot = (slot + 1) % self.policy.pipeline_depth
+            bucket = bucket_for(rows, self.buckets)
+            lits = self._assemble(batch, rows, bucket, slot)
+            # shed decision at dispatch time: backlog depth (arrivals are
+            # outpacing compute) OR slack exhaustion (the tightest
+            # deadline in the batch is inside the bucket's expected
+            # service time)
+            slack_shed = False
+            if self.policy.shed_backend is not None:
+                deadlines = [r.deadline for r in batch
+                             if r.deadline is not None]
+                if deadlines:
+                    ewma = self._svc.ewma(bucket)
+                    slack_shed = (ewma is not None and
+                                  min(deadlines) - time.monotonic() < ewma)
+            shed = (self.policy.shed_backend is not None
+                    and (self._qdepth() >= self.policy.shed_qdepth
+                         or slack_shed))
+        t_dispatch = time.monotonic()
+        waited = sum(t_dispatch - req.t_in for req in batch)
         fut = asyncio.get_running_loop().run_in_executor(
-            self._pool, self._compute, lits, bucket, batch[0].state, shed)
+            self._pool, self._compute, lits, bucket, batch[0].state, shed,
+            seq)
         with self._mu:
             self._inflight += 1
             v = batch[0].version
@@ -1299,7 +1343,9 @@ class TMServer:
                 self._inflight_versions.get(v, 0) + 1
             if shed and slack_shed:
                 self._n_slack_shed_batches += 1
-        self._completions.put_nowait((batch, rows, bucket, shed, fut))
+            self._n_waited += len(batch)
+            self._wait_s += waited
+        self._completions.put_nowait((batch, rows, bucket, shed, fut, seq))
         if self.policy.pipeline_depth == 1:
             # legacy serial semantics: this batch fully retires (compute
             # + fan-out) before the next one opens
@@ -1308,19 +1354,27 @@ class TMServer:
     # -- stage B: device compute (worker thread) ----------------------
 
     def _compute(self, lits: np.ndarray, bucket: int, state: TMState,
-                 shed: bool) -> EngineResult:
+                 shed: bool, seq: int) -> EngineResult:
         """One padded engine call, materialized to numpy (worker
         thread).  Only the engine call is traced, so XLA compiles once
         per (engine, bucket) no matter how request sizes combine; the
         wall time feeds the per-bucket service ring admission control
-        and slack shedding read."""
+        and slack shedding read.  Its spans split that time into the
+        dispatch (host work and the host→device copy), the wait for the
+        device with the first copy back (the predictions), and the other
+        copies.  The first copy does the waiting: a sync of its own
+        before the copies would wake this thread once more per batch."""
         t0 = time.perf_counter()
-        engine = (self.shed_engine_for(bucket, state) if shed
-                  else self.engine_for(bucket, state))
-        res = infer_padded(engine, lits, bucket)
-        out = EngineResult(
-            np.asarray(res.prediction), np.asarray(res.class_sums),
-            {k: np.asarray(v) for k, v in res.aux.items()})
+        with _span("tm.stageB.dispatch", batch=seq):
+            engine = (self.shed_engine_for(bucket, state) if shed
+                      else self.engine_for(bucket, state))
+            res = infer_padded(engine, lits, bucket)
+        with _span("tm.stageB.sync", batch=seq):
+            prediction = np.asarray(res.prediction)
+        with _span("tm.stageB.copy", batch=seq):
+            out = EngineResult(
+                prediction, np.asarray(res.class_sums),
+                {k: np.asarray(v) for k, v in res.aux.items()})
         self._svc.observe(bucket, time.perf_counter() - t0)
         return out
 
@@ -1337,7 +1391,7 @@ class TMServer:
             if item is _STOP:
                 self._completions.task_done()
                 return
-            batch, rows, bucket, shed, fut = item
+            batch, rows, bucket, shed, fut, seq = item
             try:
                 try:
                     res = await fut
@@ -1350,7 +1404,8 @@ class TMServer:
                     with self._mu:
                         self._n_errors += len(batch)
                 else:
-                    self._fan_out(batch, rows, bucket, shed, res)
+                    with _span("tm.fanout", batch=seq):
+                        self._fan_out(batch, rows, bucket, shed, res)
             finally:
                 with self._mu:
                     self._inflight -= 1
@@ -1411,16 +1466,15 @@ class TMServer:
         immutable, fully-computed state.  The key-chain cursor advances
         on the event loop *after* the step succeeds, so a checkpoint
         always pairs a published state with its matching cursor."""
-        import jax
-
         def learn() -> tuple:
             # advance the key chain only on success: the offline-replay
             # contract covers *applied* updates, so a failed step must
             # not consume a key
-            chain, k = jax.random.split(self._train_key)
-            new_state = self._train_engine.step(
-                self._current[1], k, upd.lits, upd.labels)
-            jax.block_until_ready(new_state.ta)
+            with _span("tm.train_step"):
+                chain, k = jax.random.split(self._train_key)
+                new_state = self._train_engine.step(
+                    self._current[1], k, upd.lits, upd.labels)
+                jax.block_until_ready(new_state.ta)
             return chain, new_state
 
         try:
@@ -1493,7 +1547,10 @@ class TMServer:
         admission rejects, and batches shed for slack exhaustion.
         ``buckets`` is the per-bucket service-time ring (count, EWMA,
         min, p50/p90/p99 ms) — the *same* numbers admission control and
-        slack shedding decide on.
+        slack shedding decide on.  ``queue_wait`` counts the requests
+        dispatched and their summed (``total_ms``) and mean (``mean_ms``)
+        wait from arrival to dispatch: coalescing, the EDF queue and the
+        pipeline semaphore, not stage B.
 
         ``tiers`` tracks the overload path: the configured shed backend
         and threshold, how many batches/rows were shed, and — whenever a
@@ -1544,6 +1601,8 @@ class TMServer:
                 "escalated_rows": self._n_escalated_rows,
                 "routing_updates": self._n_routing_updates,
                 "publish_hook_errors": self._n_publish_hook_errors,
+                "waited": self._n_waited,
+                "wait_s": self._wait_s,
             }
         p50_ms, p90_ms, p99_ms = percentiles_ms(lats, (0.50, 0.90, 0.99))
         ckpt_stats = None
@@ -1617,6 +1676,11 @@ class TMServer:
             },
             "buckets": {str(k): v
                         for k, v in sorted(self._svc.snapshot().items())},
+            "queue_wait": {
+                "requests": snap["waited"],
+                "total_ms": snap["wait_s"] * 1e3,
+                "mean_ms": snap["wait_s"] * 1e3 / max(snap["waited"], 1),
+            },
             "tiers": {
                 "shed_backend": self.policy.shed_backend,
                 "shed_qdepth": self.policy.shed_qdepth,

@@ -17,6 +17,7 @@ The acceptance contract of the lifecycle seam (docs/operations.md):
 
 import asyncio
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -317,9 +318,13 @@ def test_probe_drift_stats():
             for i in range(6):
                 await srv.submit_labeled(lits[8 * i:8 * i + 8],
                                          labels[8 * i:8 * i + 8])
-            # the update future resolves before its probe eval runs; a
-            # flushing predict (FIFO behind it) orders the stats read
-            await srv.submit(lits[:1])
+            # the update future resolves before its probe eval runs on
+            # the training thread, and no predict waits for that eval:
+            # poll until the third is counted (or give up after 30 s)
+            t_end = time.monotonic() + 30
+            while (srv.stats()["probe"]["evals"] < 3
+                   and time.monotonic() < t_end):
+                await asyncio.sleep(0.002)
             return srv.stats()["probe"], np.asarray(srv.state.ta)
 
     probe_stats, ta = asyncio.run(go())

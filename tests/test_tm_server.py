@@ -245,6 +245,34 @@ def test_warmup_and_stats_shape():
     asyncio.run(go())
 
 
+def test_queue_wait_counts_each_dispatched_request():
+    """``stats()["queue_wait"]`` counts every dispatched request once,
+    and a request's wait to dispatch is part of its latency."""
+    cfg, state = _tm(seed=13)
+    policy = ServePolicy(max_batch=4, max_wait_us=2000, backend="oracle")
+    rng = np.random.default_rng(13)
+    sizes = [1, 3, 2, 1, 4, 2, 1, 1, 3, 2, 1, 2]
+
+    async def go():
+        async with TMServer(cfg, state, policy) as server:
+            assert server.stats()["queue_wait"] == {
+                "requests": 0, "total_ms": 0.0, "mean_ms": 0.0}
+            await asyncio.gather(*[
+                server.submit(rng.integers(0, 2, (n, cfg.n_literals),
+                                           dtype=np.int8))
+                for n in sizes])
+            return server.stats()
+
+    s = asyncio.run(go())
+    wait = s["queue_wait"]
+    assert wait["requests"] == s["requests"] == len(sizes)
+    assert s["batches"] < len(sizes)           # requests were coalesced
+    assert wait["mean_ms"] == pytest.approx(wait["total_ms"] / len(sizes))
+    # every request waited, and less than its own latency; with at most
+    # 100 latencies the server's p99 is the longest (rounded to 1 us)
+    assert 0 < wait["mean_ms"] <= s["p99_ms"] + 1e-3
+
+
 @pytest.mark.slow
 @settings(max_examples=20, deadline=None)
 @given(sizes=st.lists(st.integers(min_value=1, max_value=9),
