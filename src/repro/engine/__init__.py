@@ -10,7 +10,7 @@
 from .base import (DEFAULT_BACKEND, EngineResult, ServiceStats, VoteEngine,
                    available_backends, clear_engine_cache, engine_cache_info,
                    evict_engines_for_state, get_engine, infer_padded,
-                   nearest_rank, pad_batch, register_backend,
+                   nearest_rank, pack_result, pad_batch, register_backend,
                    set_engine_cache_budget, state_nbytes,
                    weight_engines_for_state)
 from . import backends  # noqa: F401  (registers the built-in backends)
@@ -31,6 +31,7 @@ __all__ = ["DEFAULT_BACKEND", "DEFAULT_TRAIN_BACKEND", "EngineResult",
            "evict_engines_for_state", "weight_engines_for_state",
            "set_engine_cache_budget", "state_nbytes",
            "get_engine", "get_train_engine", "infer_padded", "pad_batch",
+           "pack_result",
            "register_backend", "register_train_backend",
            "export_key_cursor", "import_key_cursor", "train_engine_opts",
            "engine_from_model_config"]

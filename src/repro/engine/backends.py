@@ -39,7 +39,10 @@ XLA compilation via JAX's jit cache, so constructing an engine per call
 
 All five return bit-exact identical ``prediction`` and ``class_sums``
 (property-tested in ``tests/test_engine.py``), including tie cases
-(lowest index wins).
+(lowest index wins).  Every backend here but ``time_domain`` (whose
+``aux`` carries float latencies) also offers ``infer_packed``: its own
+jitted body under :func:`~repro.engine.base.packed_form`, the result as
+one ``(B, 1 + C)`` int32 array that a server copies back in one transfer.
 """
 
 from __future__ import annotations
@@ -58,7 +61,7 @@ from repro.kernels.clause_eval import clause_votes_pallas, make_vote_matrix
 from repro.kernels.ops import on_tpu
 from repro.kernels.swar_fused import swar_fused_votes_pallas
 
-from .base import EngineResult, register_backend
+from .base import EngineResult, packed_form, register_backend
 from .sparse import ell_from_include, sparse_clause_words
 
 __all__ = ["OracleEngine", "AdderTreeEngine", "SwarPackedEngine",
@@ -83,6 +86,9 @@ def _oracle_infer(inc, pol, literals):
     return EngineResult(argmax_tournament(sums), sums, {})
 
 
+_oracle_infer_packed = packed_form(_oracle_infer)
+
+
 @jax.jit
 def _adder_tree_infer(inc, pol, literals):
     clauses = _clause_bits(inc, literals)
@@ -91,6 +97,9 @@ def _adder_tree_infer(inc, pol, literals):
     sums = (popcount_adder_tree(clauses * pos) -
             popcount_adder_tree(clauses * neg))
     return EngineResult(argmax_tournament(sums), sums, {})
+
+
+_adder_tree_infer_packed = packed_form(_adder_tree_infer)
 
 
 def swar_clauses_votes(inc_words, pos_mask, neg_mask, literals, *, c, m):
@@ -122,6 +131,9 @@ def _swar_infer(inc_words, pos_mask, neg_mask, literals, *, c, m):
     return EngineResult(argmax_tournament(sums), sums, {})
 
 
+_swar_infer_packed = packed_form(_swar_infer, static_argnames=("c", "m"))
+
+
 @functools.partial(jax.jit, static_argnames=("block_b", "block_cm",
                                              "interpret"))
 def _swar_fused_infer(inc_words, vm, literals, *, block_b, block_cm,
@@ -133,6 +145,10 @@ def _swar_fused_infer(inc_words, vm, literals, *, block_b, block_cm,
     return EngineResult(argmax_tournament(sums), sums, {})
 
 
+_swar_fused_infer_packed = packed_form(
+    _swar_fused_infer, static_argnames=("block_b", "block_cm", "interpret"))
+
+
 @functools.partial(jax.jit, static_argnames=("c", "m"))
 def _sparse_csr_infer(indices, pol, literals, *, c, m):
     cw = sparse_clause_words(indices, literals)      # (CM, Wb) uint32
@@ -142,12 +158,20 @@ def _sparse_csr_infer(indices, pol, literals, *, c, m):
     return EngineResult(argmax_tournament(sums), sums, {})
 
 
+_sparse_csr_infer_packed = packed_form(_sparse_csr_infer,
+                                       static_argnames=("c", "m"))
+
+
 @functools.partial(jax.jit, static_argnames=("block_b", "block_cm",
                                              "interpret"))
 def _mxu_infer(inc, vm, literals, *, block_b, block_cm, interpret):
     sums = clause_votes_pallas(literals, inc, vm, block_b=block_b,
                                block_cm=block_cm, interpret=interpret)
     return EngineResult(argmax_tournament(sums), sums, {})
+
+
+_mxu_infer_packed = packed_form(
+    _mxu_infer, static_argnames=("block_b", "block_cm", "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("pdl", "n_neg"))
@@ -172,6 +196,7 @@ class OracleEngine:
     """Functional reference: einsum clause eval + ±1 dot + tournament."""
 
     _infer = staticmethod(_oracle_infer)
+    _infer_packed = staticmethod(_oracle_infer_packed)
 
     def __init__(self, cfg: TMConfig, state: TMState):
         self.cfg = cfg
@@ -181,6 +206,10 @@ class OracleEngine:
     def infer(self, literals: jax.Array) -> EngineResult:
         """(B, 2F) {0,1} literals → :class:`EngineResult` (bit-exact)."""
         return self._infer(self._inc, self._pol, literals)
+
+    def infer_packed(self, literals: jax.Array) -> jax.Array:
+        """:meth:`infer` as one ``(B, 1 + C)`` int32 array."""
+        return self._infer_packed(self._inc, self._pol, literals)
 
 
 @register_backend("adder_tree")
@@ -192,6 +221,7 @@ class AdderTreeEngine(OracleEngine):
     """
 
     _infer = staticmethod(_adder_tree_infer)
+    _infer_packed = staticmethod(_adder_tree_infer_packed)
 
 
 @register_backend("swar_packed")
@@ -220,6 +250,12 @@ class SwarPackedEngine:
                            literals, c=self.cfg.n_classes,
                            m=self.cfg.n_clauses)
 
+    def infer_packed(self, literals: jax.Array) -> jax.Array:
+        """:meth:`infer` as one ``(B, 1 + C)`` int32 array."""
+        return _swar_infer_packed(self._inc_words, self._pos_mask,
+                                  self._neg_mask, literals,
+                                  c=self.cfg.n_classes, m=self.cfg.n_clauses)
+
 
 @register_backend("swar_fused")
 class SwarFusedEngine:
@@ -246,6 +282,13 @@ class SwarFusedEngine:
                                  block_b=self._blocks[0],
                                  block_cm=self._blocks[1],
                                  interpret=not on_tpu())
+
+    def infer_packed(self, literals: jax.Array) -> jax.Array:
+        """:meth:`infer` as one ``(B, 1 + C)`` int32 array."""
+        return _swar_fused_infer_packed(self._inc_words, self._vm, literals,
+                                        block_b=self._blocks[0],
+                                        block_cm=self._blocks[1],
+                                        interpret=not on_tpu())
 
 
 @register_backend("sparse_csr")
@@ -289,6 +332,12 @@ class SparseCSREngine:
                                  c=self.cfg.n_classes,
                                  m=self.cfg.n_clauses)
 
+    def infer_packed(self, literals: jax.Array) -> jax.Array:
+        """:meth:`infer` as one ``(B, 1 + C)`` int32 array."""
+        return _sparse_csr_infer_packed(self.ell.indices, self._pol,
+                                        literals, c=self.cfg.n_classes,
+                                        m=self.cfg.n_clauses)
+
 
 @register_backend("mxu_fused")
 class MXUFusedEngine:
@@ -308,6 +357,13 @@ class MXUFusedEngine:
         return _mxu_infer(self._inc, self._vm, literals,
                           block_b=self._blocks[0], block_cm=self._blocks[1],
                           interpret=not on_tpu())
+
+    def infer_packed(self, literals: jax.Array) -> jax.Array:
+        """:meth:`infer` as one ``(B, 1 + C)`` int32 array."""
+        return _mxu_infer_packed(self._inc, self._vm, literals,
+                                 block_b=self._blocks[0],
+                                 block_cm=self._blocks[1],
+                                 interpret=not on_tpu())
 
 
 @register_backend("time_domain")

@@ -8,6 +8,10 @@ This module is the seam that makes them interchangeable in software:
 - :class:`EngineResult` — what every backend returns: the prediction, the
   signed class sums, and backend-specific per-sample extras (``aux``).
 - :class:`VoteEngine` — the protocol: ``infer(literals) -> EngineResult``.
+  A backend whose result is int32 with no ``aux`` may also offer
+  ``infer_packed(literals)``, the same result as one ``(B, 1 + C)`` array
+  (:func:`pack_result`, built by :func:`packed_form`), which a server
+  copies back to the host in one transfer.
 - a string-keyed registry (:func:`register_backend`, :func:`get_engine`,
   :func:`available_backends`) so backend choice is a config knob, not a
   code fork.
@@ -48,6 +52,7 @@ The registry cache is guarded by a lock: a serving process hits
 
 from __future__ import annotations
 
+import functools
 import threading
 import weakref
 from collections import OrderedDict
@@ -65,7 +70,8 @@ __all__ = ["EngineResult", "VoteEngine", "Registry", "KeyedEngineCache",
            "available_backends", "clear_engine_cache", "engine_cache_info",
            "evict_engines_for_state", "weight_engines_for_state",
            "set_engine_cache_budget", "state_nbytes",
-           "pad_batch", "infer_padded", "DEFAULT_BACKEND"]
+           "pad_batch", "infer_padded", "pack_result", "packed_form",
+           "DEFAULT_BACKEND"]
 
 DEFAULT_BACKEND = "oracle"
 ENGINE_CACHE_SIZE = 16
@@ -611,16 +617,103 @@ def infer_padded(engine: VoteEngine, literals: jax.Array,
     a ``time_domain`` engine built with a ``noise_key`` draws jitter
     shaped by the *padded* batch, so its per-sample noise (not its
     layout) differs from an unpadded call.
+
+    Numpy literals mark a host-side caller (serving, its warm-up): the
+    result is wanted on the host, so its copy back is asked for at once
+    (:func:`_host_result`: one transfer from an engine with
+    ``infer_packed``), and it is sliced in numpy, so no per-(bucket, b)
+    slice op is ever traced.
     """
     b = literals.shape[0]
-    res = engine.infer(pad_batch(literals, bucket))
-    if b == bucket:
-        return res
     if isinstance(literals, np.ndarray):
-        # host-side caller (the serving fan-out): slice in numpy so no
-        # per-(bucket, b) slice op is ever traced; result is numpy too
+        res = _host_result(engine, pad_batch(literals, bucket))
+        if b == bucket:
+            return res
         return EngineResult(
             np.asarray(res.prediction)[:b], np.asarray(res.class_sums)[:b],
             {k: np.asarray(v)[:b] for k, v in res.aux.items()})
+    res = engine.infer(pad_batch(literals, bucket))
+    if b == bucket:
+        return res
     return EngineResult(res.prediction[:b], res.class_sums[:b],
                         {k: v[:b] for k, v in res.aux.items()})
+
+
+def _host_result(engine: VoteEngine, literals) -> EngineResult:
+    """``engine.infer(literals)`` with the copy to the host asked for at
+    once, so the transfer queues behind the device's work and
+    ``np.asarray`` of a leaf only waits for it (the first one waits for
+    the device).
+
+    An engine with ``infer_packed`` runs that program instead, and its
+    leaves are columns of the one ``(B, 1 + C)`` array (:func:`pack_result`):
+    one transfer for the whole result, each leaf a numpy view of it.  Any
+    other engine's leaves are its own, each jax array's copy asked for
+    (numpy leaves, which a cascade's host path returns, are there
+    already).  The choice follows what the engine offers, nothing else.
+    """
+    infer_packed = getattr(engine, "infer_packed", None)
+    if infer_packed is None:
+        res = engine.infer(literals)
+        for leaf in jax.tree_util.tree_leaves(res):
+            if isinstance(leaf, jax.Array):
+                leaf.copy_to_host_async()
+        return res
+    buf = infer_packed(literals)
+    buf.copy_to_host_async()
+    b, w = buf.shape
+    return EngineResult(_HostColumns(buf, 0, (b,)),
+                        _HostColumns(buf, slice(1, None), (b, w - 1)), {})
+
+
+class _HostColumns:
+    """Columns of a device array whose copy to the host was asked for, as
+    an array-like leaf: ``np.asarray`` waits for the copy and gives a
+    view.  JAX keeps the copy, so every column view of one array shares
+    one transfer."""
+
+    def __init__(self, buf: jax.Array, cols, shape: tuple[int, ...]):
+        self._buf, self._cols = buf, cols
+        self.shape, self.dtype = shape, np.dtype(buf.dtype)
+
+    def __array__(self, dtype=None, copy=None):
+        out = np.asarray(self._buf)[:, self._cols]
+        if dtype is not None:
+            out = out.astype(dtype, copy=False)
+        return out.copy() if copy else out
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def __getitem__(self, index):
+        return np.asarray(self)[index]
+
+
+def pack_result(res: EngineResult) -> jax.Array:
+    """An extras-free result as one ``(B, 1 + C)`` array: the prediction
+    in column 0, the class sums in columns 1..C.
+
+    The layout of a backend's ``infer_packed``: a served batch then comes
+    back to the host in one transfer instead of one per leaf.  Both
+    leaves keep their dtype (int32 for every built-in backend); a result
+    with ``aux`` or with leaves of two dtypes has no packed form."""
+    if res.aux:
+        raise ValueError(f"a result with aux {sorted(res.aux)} has no "
+                         f"packed form")
+    if res.prediction.dtype != res.class_sums.dtype:
+        raise TypeError(f"prediction {res.prediction.dtype} and class sums "
+                        f"{res.class_sums.dtype} do not pack into one array")
+    return jnp.concatenate([res.prediction[:, None], res.class_sums], axis=1)
+
+
+def packed_form(infer_fn: Callable, **jit_opts) -> Callable:
+    """The jitted packed form of a backend's jitted infer body: the same
+    arguments, one :func:`pack_result` array out.  ``infer_fn`` is traced
+    inside it, so both forms share one implementation; ``jit_opts``
+    (``static_argnames``) repeat the body's.  The program is named after
+    the body with ``_packed`` appended, so a trace tells the two apart."""
+    @functools.wraps(infer_fn)
+    def packed(*args, **kwargs):
+        return pack_result(infer_fn(*args, **kwargs))
+    packed.__name__ = packed.__qualname__ = f"{infer_fn.__name__}_packed"
+    return jax.jit(packed, **jit_opts)

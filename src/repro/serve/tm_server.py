@@ -36,6 +36,10 @@ a three-stage pipeline instead of one serial loop:
 - *Stage B (device)*: the engine call runs on a single worker thread;
   up to ``pipeline_depth`` batches are in flight (a semaphore bounds
   them), so host assembly of batch ``k+1`` overlaps compute of ``k``.
+  The result's copy back is asked for at dispatch, so it queues behind
+  the device's work; an engine with ``infer_packed`` returns the whole
+  result in one array, so a batch comes back in one transfer
+  (``stats()['result_fetch']`` counts the batches by path).
 - *Stage C (fan-out)*: a dedicated coroutine consumes a FIFO completion
   queue and resolves per-request futures — awaiting clients never sit
   behind assembly of the next batch.  The worker thread is serial, so
@@ -567,6 +571,9 @@ class TMServer:
         self._n_shed_rows = 0
         self._n_cascade_rows = 0
         self._n_escalated_rows = 0
+        # served batches by how their result came back (infer_padded)
+        self._n_fetch_packed = 0
+        self._n_fetch_per_leaf = 0
         if (self.policy.shed_backend is not None
                 and self.policy.shed_backend not in available_backends()):
             raise ValueError(
@@ -936,6 +943,9 @@ class TMServer:
 
     async def warmup(self, *, train_batches: tuple[int, ...] = ()) -> None:
         """Compile every (engine, bucket) pair before taking traffic.
+        ``infer_padded`` on host literals is stage B's call, so this
+        compiles the programs that are served (the packed one where the
+        engine has it).
 
         In online-learning mode, ``train_batches`` also compiles the
         train step for those labeled-batch row counts (the update path
@@ -1360,10 +1370,14 @@ class TMServer:
         per (engine, bucket) no matter how request sizes combine; the
         wall time feeds the per-bucket service ring admission control
         and slack shedding read.  Its spans split that time into the
-        dispatch (host work and the host→device copy), the wait for the
-        device with the first copy back (the predictions), and the other
-        copies.  The first copy does the waiting: a sync of its own
-        before the copies would wake this thread once more per batch."""
+        dispatch (host work, the host→device copy, and asking for the
+        copy back, which ``infer_padded`` does for host literals), the
+        wait for the device with the first copy back (the predictions:
+        an engine with ``infer_packed`` brings the whole result in that
+        one copy), and the rest (numpy views of that copy, or the other
+        leaves' copies).
+        The first copy does the waiting: a sync of its own before it
+        would wake this thread once more per batch."""
         t0 = time.perf_counter()
         with _span("tm.stageB.dispatch", batch=seq):
             engine = (self.shed_engine_for(bucket, state) if shed
@@ -1376,6 +1390,11 @@ class TMServer:
                 prediction, np.asarray(res.class_sums),
                 {k: np.asarray(v) for k, v in res.aux.items()})
         self._svc.observe(bucket, time.perf_counter() - t0)
+        with self._mu:
+            if hasattr(engine, "infer_packed"):
+                self._n_fetch_packed += 1
+            else:
+                self._n_fetch_per_leaf += 1
         return out
 
     # -- stage C: fan-out ---------------------------------------------
@@ -1550,7 +1569,10 @@ class TMServer:
         slack shedding decide on.  ``queue_wait`` counts the requests
         dispatched and their summed (``total_ms``) and mean (``mean_ms``)
         wait from arrival to dispatch: coalescing, the EDF queue and the
-        pipeline semaphore, not stage B.
+        pipeline semaphore, not stage B.  ``result_fetch`` counts the
+        served batches by how their result came back: ``packed`` (one
+        array from an engine's ``infer_packed``) or ``per_leaf`` (a copy
+        per result array, from any other engine).
 
         ``tiers`` tracks the overload path: the configured shed backend
         and threshold, how many batches/rows were shed, and — whenever a
@@ -1603,6 +1625,8 @@ class TMServer:
                 "publish_hook_errors": self._n_publish_hook_errors,
                 "waited": self._n_waited,
                 "wait_s": self._wait_s,
+                "fetch_packed": self._n_fetch_packed,
+                "fetch_per_leaf": self._n_fetch_per_leaf,
             }
         p50_ms, p90_ms, p99_ms = percentiles_ms(lats, (0.50, 0.90, 0.99))
         ckpt_stats = None
@@ -1680,6 +1704,10 @@ class TMServer:
                 "requests": snap["waited"],
                 "total_ms": snap["wait_s"] * 1e3,
                 "mean_ms": snap["wait_s"] * 1e3 / max(snap["waited"], 1),
+            },
+            "result_fetch": {
+                "packed": snap["fetch_packed"],
+                "per_leaf": snap["fetch_per_leaf"],
             },
             "tiers": {
                 "shed_backend": self.policy.shed_backend,

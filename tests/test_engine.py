@@ -14,7 +14,8 @@ import pytest
 from repro.core.time_domain import PDLConfig, make_device
 from repro.core.tm import TMConfig, TMState, init_tm, predict
 from repro.engine import (DEFAULT_BACKEND, EngineResult, available_backends,
-                          engine_from_model_config, get_engine)
+                          engine_from_model_config, get_engine, pack_result)
+from repro.engine.base import _REGISTRY
 
 ALL_BACKENDS = available_backends()
 
@@ -286,3 +287,85 @@ def test_engine_from_model_config():
     lits = jnp.asarray(rng.integers(0, 2, (8, 24), dtype=np.int8))
     np.testing.assert_array_equal(np.asarray(eng.infer(lits).prediction),
                                   np.asarray(predict(cfg, st, lits)))
+
+
+# -- packed form: the whole result as one (B, 1 + C) int32 array ---------
+
+PACKED_BACKENDS = [b for b in ALL_BACKENDS
+                   if hasattr(_REGISTRY[b], "infer_packed")]
+SERVED_BUCKETS = (1, 2, 4, 8, 16, 32, 64)     # default_buckets(64)
+
+
+def _prototype_tm(c, m, f, *, density=0.05, noise=0.02, rows=64, seed=0):
+    """A trained-looking machine: every clause includes the same number of
+    features, with its class prototype's polarity (even clauses) or
+    another class's (odd clauses); inputs are prototypes with ``noise``
+    of their bits flipped."""
+    cfg = TMConfig(n_classes=c, n_clauses=m, n_features=f)
+    rng = np.random.default_rng(seed)
+    proto = rng.random((c, f)) < 0.5
+    k = max(1, round(2 * density * f))
+    feat = np.argsort(rng.random((c, m, f)), axis=-1).argsort(axis=-1) < k
+    clause = np.arange(m)
+    other = (np.arange(c)[:, None] + 1 + (clause[None, :] // 2)
+             % max(c - 1, 1)) % c
+    target = np.where(clause[None, :] % 2 == 0, np.arange(c)[:, None], other)
+    bits = proto[target]                                    # (C, M, F)
+    inc = np.concatenate([feat & bits, feat & ~bits], axis=-1)
+    ta = np.where(inc, cfg.n_states + 1, cfg.n_states)
+    x = proto[rng.integers(0, c, rows)] ^ (rng.random((rows, f)) < noise)
+    lits = np.concatenate([x, ~x], axis=-1).astype(np.int8)
+    return cfg, TMState(ta=jnp.asarray(ta, jnp.int32)), lits
+
+
+def _assert_packed_matches_infer(eng, lits):
+    for bucket in SERVED_BUCKETS:
+        x = jnp.asarray(lits[:bucket])
+        ref = eng.infer(x)
+        buf = eng.infer_packed(x)
+        c = ref.class_sums.shape[1]
+        assert buf.shape == (bucket, 1 + c) and buf.dtype == jnp.int32
+        host = np.asarray(buf)
+        for have, want in ((host[:, 0], ref.prediction),
+                           (host[:, 1:], ref.class_sums)):
+            want = np.asarray(want)
+            assert have.dtype == want.dtype, bucket
+            np.testing.assert_array_equal(have, want, err_msg=f"{bucket}")
+
+
+def test_packed_backends_are_the_int32_ones():
+    assert {"oracle", "adder_tree", "swar_packed", "swar_fused",
+            "sparse_csr", "mxu_fused"} <= set(PACKED_BACKENDS)
+    assert "time_domain" not in PACKED_BACKENDS
+
+
+@pytest.mark.parametrize("backend", PACKED_BACKENDS)
+def test_packed_form_equals_infer_at_every_served_bucket(backend):
+    """Bit for bit: column 0 is ``infer``'s prediction, columns 1..C its
+    class sums, at each bucket a server compiles (random machine)."""
+    cfg, st, _ = _random_tm(5, 7, 33, seed=23)
+    rng = np.random.default_rng(23)
+    lits = rng.integers(0, 2, (64, cfg.n_literals), dtype=np.int8)
+    _assert_packed_matches_infer(get_engine(backend, cfg, st), lits)
+
+
+def test_sparse_csr_packed_form_on_the_prototype_machine():
+    """A machine shaped as the bulk cell's (C=10, M=50, F=784, every
+    clause including 78 literals): every bucket bit-exact."""
+    cfg, st, lits = _prototype_tm(10, 50, 784, seed=31)
+    eng = get_engine("sparse_csr", cfg, st)
+    assert eng.ell.k_max == round(2 * 0.05 * 784)
+    _assert_packed_matches_infer(eng, lits)
+
+
+def test_pack_result_refuses_aux_and_mixed_dtypes():
+    cfg, st, lits = _random_tm(3, 6, 5, seed=29)
+    res = get_engine("time_domain", cfg, st).infer(lits)
+    with pytest.raises(ValueError, match="aux"):
+        pack_result(res)
+    with pytest.raises(TypeError, match="one array"):
+        pack_result(EngineResult(res.prediction,
+                                 res.class_sums.astype(jnp.float32), {}))
+    packed = pack_result(EngineResult(res.prediction, res.class_sums, {}))
+    np.testing.assert_array_equal(np.asarray(packed[:, 0]),
+                                  np.asarray(res.prediction))

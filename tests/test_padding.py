@@ -126,3 +126,28 @@ def test_infer_padded_exact_fit_returns_backend_result():
     ref = engine.infer(jnp.asarray(lits))
     np.testing.assert_array_equal(np.asarray(res.prediction),
                                   np.asarray(ref.prediction))
+
+
+@pytest.mark.parametrize("bucket", (4, 8), ids=("exact_fit", "padded"))
+def test_host_literals_come_back_in_one_copy(bucket):
+    """Numpy literals on an engine with ``infer_packed``: the result's
+    leaves are numpy views of one host copy of the packed array, equal
+    to ``infer`` row for row; ``np.array`` of a leaf is a copy of its own."""
+    cfg, state = _random_tm(seed=29)
+    lits = _literals(4, cfg.n_literals, seed=29)
+    engine = get_engine("sparse_csr", cfg, state)
+    res = infer_padded(engine, lits, bucket)
+    assert res.aux == {}
+    assert res.prediction.shape == (4,) and len(res.prediction) == 4
+    assert res.class_sums.shape == (4, C)
+    pred, sums = np.asarray(res.prediction), np.asarray(res.class_sums)
+    assert pred.dtype == sums.dtype == np.int32
+    # columns 0 and 1.. of one (B, 1 + C) array: sums start one int later
+    addr = lambda a: a.__array_interface__["data"][0]      # noqa: E731
+    assert addr(sums) == addr(pred) + pred.itemsize
+    assert pred.strides[0] == sums.strides[0] == (1 + C) * pred.itemsize
+    _assert_rows_equal(res, engine.infer(jnp.asarray(lits)), 4)
+    own = np.array(res.class_sums)
+    own[0, 0] += 1
+    assert np.asarray(res.class_sums)[0, 0] == own[0, 0] - 1
+    assert res.class_sums[0, 0] == sums[0, 0]

@@ -273,6 +273,111 @@ def test_queue_wait_counts_each_dispatched_request():
     assert 0 < wait["mean_ms"] <= s["p99_ms"] + 1e-3
 
 
+# -- stage B's result fetch: one packed array, or a copy per leaf --------
+
+def _server_for(kind, cfg, state):
+    """A server whose stage B serves ``kind``'s engine, and whether
+    ``_compute`` should take the shed tier."""
+    if kind == "cascade_shed":
+        policy = ServePolicy(max_batch=8, backend="oracle",
+                             shed_backend="cascade")
+        return TMServer(cfg, state, policy), True
+    backend = "time_domain" if kind == "time_domain" else "sparse_csr"
+    mesh = 2 if kind == "sharded" else None
+    return TMServer(cfg, state, ServePolicy(max_batch=8, backend=backend),
+                    mesh=mesh), False
+
+
+@pytest.mark.parametrize("kind, packed, aux", [
+    pytest.param("sparse_csr", True, {}, id="sparse_csr"),
+    pytest.param("time_domain", False, {"latency_ps": np.float32,
+                                        "metastable": np.bool_},
+                 id="time_domain"),
+    pytest.param("cascade_shed", False, {"escalated": np.bool_},
+                 id="cascade_shed"),
+    pytest.param("sharded", False, {}, id="sharded"),
+])
+def test_compute_returns_numpy_results(kind, packed, aux):
+    """``_compute`` hands stage C numpy leaves of the bucket's shape and
+    the engine's dtypes, equal to the engine's own ``infer``, whichever
+    way the result came back."""
+    cfg, state = _tm(seed=14, density=0.05)
+    rng = np.random.default_rng(14)
+
+    async def go():
+        server, shed = _server_for(kind, cfg, state)
+        async with server:
+            for seq, bucket in enumerate(server.buckets):
+                eng = (server.shed_engine_for(bucket) if shed
+                       else server.engine_for(bucket))
+                assert hasattr(eng, "infer_packed") == packed
+                lits = rng.integers(0, 2, (bucket, cfg.n_literals),
+                                    dtype=np.int8)
+                res = server._compute(lits, bucket, server.state, shed, seq)
+                ref = eng.infer(jnp.asarray(lits))
+                assert isinstance(res.prediction, np.ndarray)
+                assert res.prediction.shape == (bucket,)
+                assert res.prediction.dtype == np.int32
+                assert isinstance(res.class_sums, np.ndarray)
+                assert res.class_sums.shape == (bucket, C)
+                assert res.class_sums.dtype == np.int32
+                assert {k: v.dtype.type for k, v in res.aux.items()} == aux
+                for k, v in res.aux.items():
+                    assert isinstance(v, np.ndarray) and v.shape[0] == bucket
+                    np.testing.assert_array_equal(v, np.asarray(ref.aux[k]))
+                np.testing.assert_array_equal(res.prediction,
+                                              np.asarray(ref.prediction))
+                np.testing.assert_array_equal(res.class_sums,
+                                              np.asarray(ref.class_sums))
+            n = len(server.buckets)
+            assert server.stats()["result_fetch"] == {
+                "packed": n if packed else 0, "per_leaf": 0 if packed else n}
+
+    asyncio.run(go())
+
+
+@pytest.mark.parametrize("backend, path", [("sparse_csr", "packed"),
+                                           ("time_domain", "per_leaf")])
+def test_result_fetch_counts_each_batch_by_its_path(backend, path):
+    cfg, state = _tm(seed=15, density=0.05)
+    reqs = _requests(cfg, [1, 3, 2, 5, 1, 8, 2], seed=15)
+    policy = ServePolicy(max_batch=8, max_wait_us=500, backend=backend)
+    results, completions, stats = _serve_all(cfg, state, policy, reqs)
+    _check_contract(cfg, state, reqs, results, completions)
+    other = "per_leaf" if path == "packed" else "packed"
+    assert stats["result_fetch"][path] == stats["batches"] > 0
+    assert stats["result_fetch"][other] == 0
+
+
+def test_warmup_compiles_the_served_program():
+    """The warm-up compiles the packed program once per bucket, and
+    serving every bucket afterwards adds nothing to its jit cache."""
+    from repro.engine.backends import _sparse_csr_infer_packed
+    # a shape no other test serves, so only this server fills the cache
+    cfg = TMConfig(n_classes=4, n_clauses=11, n_features=13)
+    rng = np.random.default_rng(16)
+    ta = np.where(rng.random((4, 11, cfg.n_literals)) < 0.05,
+                  cfg.n_states + 1, cfg.n_states)
+    state = TMState(ta=jnp.asarray(ta, jnp.int32))
+
+    async def go():
+        policy = ServePolicy(max_batch=8, max_wait_us=0, backend="sparse_csr")
+        async with TMServer(cfg, state, policy) as server:
+            before = _sparse_csr_infer_packed._cache_size()
+            await server.warmup()
+            warm = _sparse_csr_infer_packed._cache_size()
+            assert warm - before == len(server.buckets)
+            for b in server.buckets:
+                await server.submit(np.zeros((b, cfg.n_literals), np.int8))
+            assert _sparse_csr_infer_packed._cache_size() == warm
+            s = server.stats()
+            assert s["batches"] == len(server.buckets)
+            assert s["result_fetch"] == {"packed": len(server.buckets),
+                                         "per_leaf": 0}
+
+    asyncio.run(go())
+
+
 @pytest.mark.slow
 @settings(max_examples=20, deadline=None)
 @given(sizes=st.lists(st.integers(min_value=1, max_value=9),

@@ -77,13 +77,18 @@ def test_vote_kernel_compiles(one_chip, backend, bucket):
 
 
 def test_sparse_csr_serve_route_compiles(one_chip):
-    """The route the server takes at a trained machine's ~5% density."""
+    """The route the server takes at a trained machine's ~5% density: the
+    packed program it serves by, and the plain ``infer``."""
     from repro.engine import backends
-    hlo = backends._sparse_csr_infer.lower(
-        _shape(one_chip, (C * M, 128), jnp.int32),
-        _shape(one_chip, (M,), jnp.int32),
-        _shape(one_chip, (64, L), jnp.int8), c=C, m=M).compile().as_text()
-    assert hlo
+    args = (_shape(one_chip, (C * M, 128), jnp.int32),
+            _shape(one_chip, (M,), jnp.int32),
+            _shape(one_chip, (64, L), jnp.int8))
+    assert backends._sparse_csr_infer.lower(
+        *args, c=C, m=M).compile().as_text()
+    packed = backends._sparse_csr_infer_packed.lower(
+        *args, c=C, m=M).compile()
+    assert packed.out_info.shape == (64, 1 + C)
+    assert packed.out_info.dtype == jnp.int32
 
 
 def test_popcount_kernel_compiles(one_chip):
