@@ -120,12 +120,15 @@ completion before the next opens, updates quiesce predicts).
   in :meth:`stats`.  Operator procedures: docs/operations.md.
 
 - tracing — every stage above records a ``jax.profiler`` span (the
-  names in :data:`SPANS`), so a profiler trace of a live server shows
-  the host's stages on the same clock as the device's ops; each batch's
-  spans carry its sequence number as ``batch=<n>``.  With the profiler
+  names in :data:`SPANS`; ``tm.engine_build`` nests in ``tm.publish``),
+  so a profiler trace of a live server shows the host's stages on the
+  same clock as the device's ops; each batch's spans carry its sequence
+  number as ``batch=<n>``.  With the profiler
   off a span costs about a microsecond and adds no device work.
   ``stats()['queue_wait']`` counts the time requests waited between
-  arrival and dispatch.  What each span covers: docs/operations.md.
+  arrival and dispatch, ``stats()['engine_build']`` the publishes and
+  the seconds of their serving builds.  What each span covers:
+  docs/operations.md.
 
 Ordering caveat: a single client with *multiple concurrently
 outstanding* requests carrying different deadlines/priorities may see
@@ -179,7 +182,10 @@ _span = jax.profiler.TraceAnnotation
 #: ``tm.stageB.dispatch``, ``tm.stageB.sync``, ``tm.stageB.copy``
 #: (stage B, worker thread); ``tm.fanout`` (stage C); ``tm.publish``
 #: (state swap and serving refresh) and ``tm.train_step`` (training
-#: thread).  A batch's spans carry ``batch=<n>``, a publish ``version=<n>``.
+#: thread).  A batch's spans carry ``batch=<n>``, a publish
+#: ``version=<n>``.  Inside ``tm.publish``, ``tm.engine_build`` covers
+#: the serving build (``stats()["engine_build"]`` times it); it is not
+#: listed here, as ``bench/spans.py`` mirrors this tuple name for name.
 SPANS = ("tm.idle", "tm.coalesce", "tm.pipeline_wait", "tm.assemble",
          "tm.stageB.dispatch", "tm.stageB.sync", "tm.stageB.copy",
          "tm.fanout", "tm.publish", "tm.train_step")
@@ -458,6 +464,10 @@ class TMServer:
         # swapped as one tuple so lock-free readers see a matched pair
         self._serve_ell: IncrementalEll | None = None
         self._sparse_serving: tuple[TMState, object] | None = None
+        # publishes and the seconds spent in their serving build
+        # (tm.engine_build), over the server's life
+        self._n_engine_builds = 0
+        self._engine_build_s = 0.0
         # fleet seam: called as on_publish(version, state) after every
         # publish (including this constructor one); hook errors are
         # contained (counted, never propagated into the update path)
@@ -639,32 +649,44 @@ class TMServer:
            leak until LRU pressure; in-flight predicts still pinned to
            the old version just rebuild on a cache miss).
         """
-        inc = np.asarray(
-            include_mask(self.cfg, state), dtype=bool).reshape(
-            self.cfg.n_classes * self.cfg.n_clauses, self.cfg.n_literals)
-        if not self._routing_pinned:
-            new_routes = route_buckets(self.cfg, state, self.buckets,
-                                       density=float(inc.mean()))
-            if new_routes != self.routing:
-                self.routing = new_routes
-                with self._mu:
-                    self._n_routing_updates += 1
-        if "sparse_csr" in self.routing.values():
-            if self._serve_ell is None:
-                self._serve_ell = IncrementalEll(inc)
+        t0 = time.perf_counter()
+        with _span("tm.engine_build"):
+            # pinned routes without sparse_csr read no host mask (at
+            # C·M·2F = 200 M literals its copy alone takes seconds)
+            if self._routing_pinned and \
+                    "sparse_csr" not in self.routing.values():
+                inc = None
             else:
-                self._serve_ell.refresh(inc)
-            engine = get_engine("sparse_csr", self.cfg, state, cache=False,
-                                ell=self._serve_ell.layout)
-            if self._mesh is not None:
-                # the one-slot engine bypasses get_engine's shard_batch
-                # wrapping (cache=False + EllLayout opts), so wrap here —
-                # mesh-configured serving must cover the sparse route too
-                from repro.engine.sharding import ShardedEngine
-                engine = ShardedEngine(engine, mesh=self._mesh)
-            self._sparse_serving = (state, engine)
-        else:
-            self._sparse_serving = None
+                inc = np.asarray(
+                    include_mask(self.cfg, state), dtype=bool).reshape(
+                    self.cfg.n_classes * self.cfg.n_clauses,
+                    self.cfg.n_literals)
+            if not self._routing_pinned:
+                new_routes = route_buckets(self.cfg, state, self.buckets,
+                                           density=float(inc.mean()))
+                if new_routes != self.routing:
+                    self.routing = new_routes
+                    with self._mu:
+                        self._n_routing_updates += 1
+            if "sparse_csr" in self.routing.values():
+                if self._serve_ell is None:
+                    self._serve_ell = IncrementalEll(inc)
+                else:
+                    self._serve_ell.refresh(inc)
+                engine = get_engine("sparse_csr", self.cfg, state, cache=False,
+                                    ell=self._serve_ell.layout)
+                if self._mesh is not None:
+                    # the one-slot engine bypasses get_engine's shard_batch
+                    # wrapping (cache=False + EllLayout opts), so wrap here —
+                    # mesh-configured serving must cover the sparse route too
+                    from repro.engine.sharding import ShardedEngine
+                    engine = ShardedEngine(engine, mesh=self._mesh)
+                self._sparse_serving = (state, engine)
+            else:
+                self._sparse_serving = None
+        with self._mu:
+            self._n_engine_builds += 1
+            self._engine_build_s += time.perf_counter() - t0
         if superseded is not None and superseded is not state:
             evict_engines_for_state(superseded)
 
@@ -1572,7 +1594,13 @@ class TMServer:
         pipeline semaphore, not stage B.  ``result_fetch`` counts the
         served batches by how their result came back: ``packed`` (one
         array from an engine's ``infer_packed``) or ``per_leaf`` (a copy
-        per result array, from any other engine).
+        per result array, from any other engine).  ``engine_build``
+        counts the publishes since construction (the constructor's own
+        included) and sums the ``seconds`` their serving build took: the
+        include mask's copy to the host, the route re-resolution, the
+        ELL refresh and the ``sparse_csr`` engine's build (the
+        ``tm.engine_build`` span); a route pinned to another backend
+        skips all four, its engines built on first use.
 
         ``tiers`` tracks the overload path: the configured shed backend
         and threshold, how many batches/rows were shed, and — whenever a
@@ -1627,6 +1655,8 @@ class TMServer:
                 "wait_s": self._wait_s,
                 "fetch_packed": self._n_fetch_packed,
                 "fetch_per_leaf": self._n_fetch_per_leaf,
+                "engine_builds": self._n_engine_builds,
+                "engine_build_s": self._engine_build_s,
             }
         p50_ms, p90_ms, p99_ms = percentiles_ms(lats, (0.50, 0.90, 0.99))
         ckpt_stats = None
@@ -1708,6 +1738,10 @@ class TMServer:
             "result_fetch": {
                 "packed": snap["fetch_packed"],
                 "per_leaf": snap["fetch_per_leaf"],
+            },
+            "engine_build": {
+                "count": snap["engine_builds"],
+                "seconds": snap["engine_build_s"],
             },
             "tiers": {
                 "shed_backend": self.policy.shed_backend,

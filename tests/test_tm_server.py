@@ -273,6 +273,73 @@ def test_queue_wait_counts_each_dispatched_request():
     assert 0 < wait["mean_ms"] <= s["p99_ms"] + 1e-3
 
 
+
+@pytest.mark.parametrize("backend", [None, "oracle"])
+def test_engine_build_counts_each_publish(backend):
+    """``stats()["engine_build"]`` counts the constructor's publish and
+    each later one, and sums positive seconds, whether the route builds
+    a ``sparse_csr`` engine (density route) or not (pinned ``oracle``)."""
+    cfg, state = _tm(seed=14, density=0.05)
+    server = TMServer(cfg, state, ServePolicy(max_batch=4, backend=backend))
+    build = server.stats()["engine_build"]
+    assert build["count"] == 1 and build["seconds"] > 0
+    seconds = [build["seconds"]]
+    for i in range(2):
+        ta = state.ta.at[0, 0, i].set(cfg.n_states + 1)
+        server.publish(TMState(ta=ta))
+        build = server.stats()["engine_build"]
+        assert build["count"] == 2 + i
+        seconds.append(build["seconds"])
+    assert seconds[0] < seconds[1] < seconds[2]
+    assert (server.stats()["sparse_layout"] is None) == (backend is not None)
+
+
+@pytest.mark.parametrize("backend,copies", [
+    (None, 1), ("sparse_csr", 1), ("swar_fused", 0), ("oracle", 0)])
+def test_publish_copies_the_mask_only_where_a_route_reads_it(
+        monkeypatch, backend, copies):
+    """A publish brings the include mask to the host for the density
+    route or the ELL; a route pinned to another backend reads neither."""
+    import repro.serve.tm_server as tm_server
+    cfg, state = _tm(seed=15, density=0.05)
+    server = TMServer(cfg, state, ServePolicy(max_batch=4, backend=backend))
+    calls = []
+    real = tm_server.include_mask
+    monkeypatch.setattr(tm_server, "include_mask",
+                        lambda *a: calls.append(1) or real(*a))
+    server.publish(TMState(ta=state.ta.at[0, 0, 0].set(cfg.n_states + 1)))
+    assert len(calls) == copies
+    assert server.stats()["engine_build"]["count"] == 2
+    lits = jnp.asarray(np.random.default_rng(0).integers(
+        0, 2, (4, cfg.n_literals)), jnp.int8)
+    want = get_engine("oracle", cfg, server.state).infer(lits)
+    got = server.engine_for(4).infer(lits)
+    np.testing.assert_array_equal(np.asarray(got.class_sums),
+                                  np.asarray(want.class_sums))
+
+
+
+def test_engine_build_span_nests_in_publish(tmp_path):
+    """A profiler trace of a publish holds one ``tm.engine_build`` span
+    inside its ``tm.publish`` span."""
+    import jax
+    cfg, state = _tm(seed=16, density=0.05)
+    server = TMServer(cfg, state, ServePolicy(max_batch=4))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        server.publish(TMState(ta=state.ta.at[0, 0, 0].set(cfg.n_states)))
+    finally:
+        jax.profiler.stop_trace()
+    [path] = tmp_path.rglob("*.xplane.pb")
+    events = [ev for plane in jax.profiler.ProfileData.from_file(
+        str(path)).planes for line in plane.lines for ev in line.events]
+    [publish] = [e for e in events if e.name == "tm.publish"]
+    [build] = [e for e in events if e.name == "tm.engine_build"]
+    assert publish.start_ns <= build.start_ns
+    assert build.start_ns + build.duration_ns \
+        <= publish.start_ns + publish.duration_ns
+
+
 # -- stage B's result fetch: one packed array, or a copy per leaf --------
 
 def _server_for(kind, cfg, state):

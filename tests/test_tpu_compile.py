@@ -6,7 +6,9 @@ would refuse (a Mosaic lowering with no TPU rule, an unaligned block, a
 program that does not fit).  Interpret-mode tests cannot see any of that.
 Every compile here is at the width of ``tm-mnist-100`` (C=10, M=100,
 F=784, so L=1568 literals), the serving buckets 1 and 64, and the label
-batch 32.  Nothing runs: these tests prove compilation only.
+batch 32; the inference routes also at the width of ``tm-imdb-10k``
+(C=2, M=10,000, F=5,000, so L=10,000).  Nothing runs: these tests prove
+compilation only.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and every xdist
@@ -27,6 +29,9 @@ C, M, F = 10, 100, 784                       # configs/tm_paper.py
 L = 2 * F
 WL = -(-L // 32)                             # literal words
 WM = -(-M // 32)                             # clause-polarity words
+# bench/configs/tm-imdb-10k.json: 500 included literals a clause, so the
+# server's ELL width is 500 + the 8-slot slack = 512
+TEXT_C, TEXT_M, TEXT_L, TEXT_K = 2, 10000, 10000, 512
 LABEL_BATCH = 32
 CFG = TMConfig(n_classes=C, n_clauses=M, n_features=F, T=5, s=10.0)
 
@@ -89,6 +94,42 @@ def test_sparse_csr_serve_route_compiles(one_chip):
         *args, c=C, m=M).compile()
     assert packed.out_info.shape == (64, 1 + C)
     assert packed.out_info.dtype == jnp.int32
+
+
+@pytest.mark.parametrize("backend", ["swar_fused", "mxu_fused"])
+def test_vote_kernel_compiles_at_text_width(one_chip, backend):
+    """The kernel routes at ``tm-imdb-10k`` width, bucket 64, with the
+    default tiles: each block fits the chip's scoped VMEM."""
+    from repro.engine import backends
+    s = lambda shape, dt: _shape(one_chip, shape, dt)     # noqa: E731
+    cm = TEXT_C * TEXT_M
+    vm = s((cm, TEXT_C), jnp.int8)
+    lits = s((64, TEXT_L), jnp.int8)
+    if backend == "swar_fused":
+        fn = backends._swar_fused_infer_packed
+        args = (s((cm, -(-TEXT_L // 32)), jnp.uint32), vm, lits)
+        tiles = dict(block_b=8, block_cm=128)
+    else:
+        fn = backends._mxu_infer_packed
+        args = (s((cm, TEXT_L), jnp.int8), vm, lits)
+        tiles = dict(block_b=128, block_cm=128)
+    compiled = fn.lower(*args, interpret=False, **tiles).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (64, 1 + TEXT_C)
+
+
+@pytest.mark.parametrize("bucket", [1, 64])
+def test_sparse_csr_serve_route_compiles_at_text_width(one_chip, bucket):
+    """The density route the server takes at ``tm-imdb-10k``: the packed
+    program over 20,000 clause rows of 512 ELL slots fits one chip."""
+    from repro.engine import backends
+    packed = backends._sparse_csr_infer_packed.lower(
+        _shape(one_chip, (TEXT_C * TEXT_M, TEXT_K), jnp.int32),
+        _shape(one_chip, (TEXT_M,), jnp.int32),
+        _shape(one_chip, (bucket, TEXT_L), jnp.int8),
+        c=TEXT_C, m=TEXT_M).compile()
+    assert packed.out_info.shape == (bucket, 1 + TEXT_C)
+    assert packed.memory_analysis().temp_size_in_bytes < 16 * 2**30
 
 
 def test_popcount_kernel_compiles(one_chip):
